@@ -1,8 +1,7 @@
 # Repro of "A Flexible Thread Scheduler for Hierarchical Multiprocessor
 # Machines" — developer/CI entry points.
 #
-#   make test         tier-1 gate: the full pytest suite (hypothesis optional;
-#                     tests/_hypothesis_shim.py covers clean environments)
+#   make test         tier-1 gate: the full pytest suite
 #   make lint         fast syntax gate: byte-compile src/tests/benchmarks +
 #                     docs-reference check (README/docs code pointers resolve)
 #   make bench-smoke  seconds-scale benchmark sanity run (Table 2 conduction

@@ -29,6 +29,7 @@ import shutil
 from pathlib import Path
 from typing import Any, Optional
 
+import ml_dtypes
 import numpy as np
 
 _BF16 = "bfloat16"
@@ -74,11 +75,7 @@ def _decode(spec, dirpath: Path):
         return items if spec["t"] == "list" else tuple(items)
     arr = np.load(dirpath / spec["file"])
     if spec["dtype"] == _BF16:
-        try:
-            import ml_dtypes
-            arr = arr.view(ml_dtypes.bfloat16)
-        except ImportError:              # numpy-only env: hand back uint16
-            pass                         # bits; the jax backend re-views
+        arr = arr.view(ml_dtypes.bfloat16)
     return arr
 
 

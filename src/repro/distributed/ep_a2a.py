@@ -30,7 +30,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -109,9 +108,9 @@ def make_ep_ffn(mesh: Mesh, axis: str, n_experts: int, top_k: int,
     # holds a distinct slice of the batch (standard EP: batch × expert grid)
     pspec_w = P(axis)            # expert-sharded weights (E dim leading)
     pspec_x = P(axis)            # batch slice per expert shard
-    f = shard_map(ep_ffn, mesh=mesh,
-                  in_specs=(pspec_w, pspec_w, pspec_w, pspec_x, pspec_x,
-                            pspec_x),
-                  out_specs=pspec_x,
-                  check_rep=False)
+    f = jax.shard_map(ep_ffn, mesh=mesh,
+                      in_specs=(pspec_w, pspec_w, pspec_w, pspec_x, pspec_x,
+                                pspec_x),
+                      out_specs=pspec_x,
+                      check_vma=False)
     return f

@@ -10,8 +10,8 @@ scalar-prefetched block table.
 
 Structure follows ``flash_attention._kernel`` (the online-softmax VMEM
 scratch pattern): the page axis is the innermost grid dimension, iterated
-sequentially per (slot, kv-head), so (m, l, acc) carry across pages.  The
-block table and per-slot lengths ride in scalar-prefetch memory
+sequentially per slot, so (m, l, acc) carry across pages.  The block
+table and per-slot lengths ride in scalar-prefetch memory
 (``PrefetchScalarGridSpec``) because the K/V BlockSpec index map *is* the
 table lookup — the DMA for page ``i`` of slot ``b`` fetches pool page
 ``tables[b, i]``.
@@ -21,10 +21,21 @@ Layout: q ``(B, K, g, hd)`` (GQA groups folded out of H = K*g), pools
 lengths ``(B,)`` int32 — the number of valid tokens *including* the one
 just written; the query is the token at position ``lengths - 1``.  Unused
 table entries must be 0: page 0 is the engine's trash page, never valid,
-and masked off by the length test.  On real TPUs ``page_size`` should be a
-sublane multiple (8 for f32); interpret mode (the CPU CI path) has no such
-constraint.  Validated against ``ref.sdpa_ref`` / ``ref.paged_sdpa_ref``
-in interpret mode by ``tests/test_paged_attention.py``.
+and masked off by the length test.
+
+Tiling: Mosaic requires the last two dims of every block to be multiples
+of the dtype's (sublane, lane) tile — (8, 128) for f32, (16, 128) for
+bf16 — or equal to the array's own dims.  A per-head block
+``(1, page_size, 1, hd)`` fails that on the head axis, so the kernel sees
+each pool as ``(num_pages, page_size, K*hd)`` (a free reshape of the
+contiguous trailing dims) and fetches one whole page — all KV heads — per
+grid step: the block's last two dims are then the array's, legal for any
+``page_size``.  Each head is a static lane slice of the page.  For tile-
+aligned VMEM use ``page_size`` should still be a sublane multiple (16 for
+bf16) and ``hd`` a multiple of 128.  Validated against ``ref.sdpa_ref`` /
+``ref.paged_sdpa_ref`` in interpret mode by
+``tests/test_paged_attention.py``; compiled for a described v5e by
+``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -41,9 +52,10 @@ NEG_INF = -1e30
 
 def _kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float,
-            window: Optional[int], page_size: int, npages: int):
+            window: Optional[int], page_size: int, npages: int,
+            K: int, hd: int):
     b = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -51,39 +63,41 @@ def _kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (page_size, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (g, ps)
-
     # position of each pool column = page rank * page_size + offset; valid
     # while < lengths[b] (and, for SWA, within `window` of the query).  A
     # page past the slot's used count points at the trash page — every one
     # of its positions fails the length test, so its contents never leak.
+    g = q_ref.shape[2]
     length = len_ref[b]
     k_pos = ip * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
+        jnp.int32, (g, page_size), 1)
     mask = k_pos < length
     if window is not None:
         mask &= k_pos > (length - 1 - window)
-    s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                                  # (g,)
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])                      # (g, ps)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    for h in range(K):                                   # static: K is small
+        q = q_ref[0, h].astype(jnp.float32) * scale      # (g, hd)
+        k = k_ref[0, :, h * hd:(h + 1) * hd].astype(jnp.float32)  # (ps, hd)
+        v = v_ref[0, :, h * hd:(h + 1) * hd].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (g, ps)
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_ref[h]                                # (g, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                           # (g, ps)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
     @pl.when(ip == npages - 1)
     def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        for h in range(K):
+            l = jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
 
 def paged_attn(q, k_pool, v_pool, tables, lengths, *,
@@ -107,23 +121,21 @@ def paged_attn(q, k_pool, v_pool, tables, lengths, *,
     from jax.experimental.pallas import tpu as pltpu
 
     kern = functools.partial(_kernel, scale=scale, window=window,
-                             page_size=page_size, npages=npages)
+                             page_size=page_size, npages=npages, K=K, hd=hd)
+    page = pl.BlockSpec((1, page_size, K * hd),
+                        lambda b, ip, tbl, ln: (tbl[b, ip], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, K, npages),
+        grid=(B, npages),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda b, h, ip, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, h, 0)),
+            pl.BlockSpec((1, K, g, hd), lambda b, ip, tbl, ln: (b, 0, 0, 0)),
+            page, page,
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b, h, ip, tbl, ln: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, hd), jnp.float32),
-                        pltpu.VMEM((g,), jnp.float32),
-                        pltpu.VMEM((g,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, K, g, hd),
+                               lambda b, ip, tbl, ln: (b, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((K, g, hd), jnp.float32),
+                        pltpu.VMEM((K, g, 1), jnp.float32),
+                        pltpu.VMEM((K, g, 1), jnp.float32)],
     )
     return pl.pallas_call(
         kern,
@@ -131,4 +143,5 @@ def paged_attn(q, k_pool, v_pool, tables, lengths, *,
         out_shape=jax.ShapeDtypeStruct((B, K, g, hd), q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
+      q, k_pool.reshape(P, page_size, K * hd),
+      v_pool.reshape(P, page_size, K * hd))

@@ -29,20 +29,16 @@ def _kernel(a_ref, b_ref, h_ref, carry_ref, *, chunk: int):
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    a = a_ref[0].astype(jnp.float32)          # (chunk, N)
-    b = b_ref[0].astype(jnp.float32)
-    h0 = carry_ref[...]                        # (N,)
+    # rows are read and written through the refs: indexing a loaded value
+    # at a traced ``t`` lowers to ``dynamic_slice``, which Mosaic lacks
+    def step(t, h):                            # h: (1, N)
+        row = pl.ds(t, 1)
+        h = (a_ref[0, row, :].astype(jnp.float32) * h
+             + b_ref[0, row, :].astype(jnp.float32))
+        h_ref[0, row, :] = h.astype(h_ref.dtype)
+        return h
 
-    def step(t, carry_and_out):
-        h, out = carry_and_out
-        h = a[t] * h + b[t]
-        out = jax.lax.dynamic_update_index_in_dim(out, h, t, 0)
-        return h, out
-
-    out0 = jnp.zeros((chunk, a.shape[1]), jnp.float32)
-    h, out = jax.lax.fori_loop(0, chunk, step, (h0, out0))
-    h_ref[0] = out.astype(h_ref.dtype)
-    carry_ref[...] = h
+    carry_ref[...] = jax.lax.fori_loop(0, chunk, step, carry_ref[...])
 
 
 def lru_scan(a, b, *, chunk: int = 256, interpret: Optional[bool] = None):
@@ -66,6 +62,6 @@ def lru_scan(a, b, *, chunk: int = 256, interpret: Optional[bool] = None):
         ],
         out_specs=pl.BlockSpec((1, chunk, N), lambda ib, ic: (ib, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((N,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, N), jnp.float32)],
         interpret=interpret,
     )(a, b)

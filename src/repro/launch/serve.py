@@ -99,9 +99,11 @@ def main(argv=None):
     else:
         import jax
         from repro.configs import ARCHS, get_config
+        from repro.launch.compile_cache import use_compile_cache
         from repro.models import api
         if args.arch not in ARCHS:
             raise SystemExit(f"unknown arch {args.arch!r}")
+        use_compile_cache()
         cfg = get_config(args.arch)
         if args.reduced:
             cfg = cfg.reduced()

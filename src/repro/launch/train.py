@@ -1,7 +1,7 @@
 """Training driver: bubble-planned sharded train loop with fault tolerance.
 
-Runs on any mesh (1x1 on this CPU container; 16x16 / 2x16x16 in
-production — same code path).  Features:
+Runs on any mesh (1x1 on a CPU; 2x2 on a four-chip v5e host; 16x16 /
+2x16x16 in production — same code path).  Features:
 
 * bubble-planner-derived shardings (``--strategy bubbles|simple|bound``)
 * AdamW with fp32 master + bf16 moments, ZeRO-1 over ``data``
@@ -19,6 +19,7 @@ Example (CPU smoke):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 from pathlib import Path
 
@@ -32,6 +33,7 @@ from repro.core.planner import MeshAxis, plan_bubbles, plan_simple
 from repro.data import DataConfig, PrefetchBuffer, ShardedTokenStream
 from repro.distributed import sharding as shard_mod
 from repro.distributed.fault_tolerance import StragglerDetector
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh, mesh_axes
 from repro.models import api
 from repro.optim import adamw
@@ -59,7 +61,11 @@ def build_train_step(cfg, acfg, use_compression: bool = False):
     return train_step
 
 
-def main(argv=None):
+def run(argv=None) -> dict:
+    """Parse ``argv``, train, and return ``{"losses": [...],
+    "state_bytes": {device: bytes}, "state_total": bytes}`` — the
+    per-step losses, the parameter + optimizer bytes each device holds
+    after placement, and the bytes of one whole copy of that state."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b", choices=ARCHS)
     ap.add_argument("--steps", type=int, default=20)
@@ -68,20 +74,29 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--reduced", action="store_true",
                     help="tiny same-family config (CPU)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers (widths kept)")
     ap.add_argument("--strategy", default="bubbles",
                     choices=["bubbles", "simple"])
     ap.add_argument("--mesh", default="1x1",
                     help="e.g. 1x1, 2x4, 2x16x16 (axes inferred)")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint here every --ckpt-every steps and at "
+                         "the end (default: no checkpoints)")
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if args.resume and args.ckpt_dir is None:
+        ap.error("--resume needs --ckpt-dir")
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
 
     shape = tuple(int(x) for x in args.mesh.split("x"))
     axes = {1: ("data",), 2: ("data", "model"),
@@ -98,19 +113,19 @@ def main(argv=None):
             else plan_simple("batch", maxes))
     print(plan.pretty())
 
-    with mesh:
-        pspec_tree = shard_mod.param_specs(cfg, plan, mesh)
-        p_sh = jax.tree.map(
-            lambda s: jax.sharding.NamedSharding(mesh, s), pspec_tree)
-        o_sh = jax.tree.map(
-            lambda s: jax.sharding.NamedSharding(mesh, s),
-            shard_mod.opt_specs(cfg, plan, mesh))
+    def named(specs):
+        return jax.tree.map(
+            lambda s: jax.sharding.NamedSharding(mesh, s), specs)
 
-        key = jax.random.PRNGKey(args.seed)
-        params = api.init(cfg, key)
-        params = jax.tree.map(jax.device_put, params, p_sh)
+    with mesh:
+        p_sh = named(shard_mod.param_specs(cfg, plan, mesh))
+        o_sh = named(shard_mod.opt_specs(cfg, plan, mesh))
+
+        # every leaf is made on the devices its sharding names: nothing
+        # is built whole on the default device first
+        params = api.init(cfg, jax.random.PRNGKey(args.seed), p_sh)
         acfg = adamw.AdamWConfig(lr=args.lr)
-        opt = adamw.init(params)
+        opt = jax.jit(adamw.init, out_shardings=o_sh)(params)
 
         start = 0
         if args.resume:
@@ -119,14 +134,26 @@ def main(argv=None):
                 params, _ = ckpt.restore(args.ckpt_dir, latest, params,
                                          shardings=p_sh)
                 opt, _ = ckpt.restore(Path(args.ckpt_dir) / "opt", latest,
-                                      opt)
+                                      opt, shardings=o_sh)
                 start = latest
                 print(f"resumed from step {latest}")
+
+        state_bytes: dict = {}
+        for leaf in jax.tree.leaves((params, opt)):
+            for sh in leaf.addressable_shards:
+                d = str(sh.device)
+                state_bytes[d] = state_bytes.get(d, 0) + sh.data.nbytes
+        state_total = sum(leaf.nbytes
+                          for leaf in jax.tree.leaves((params, opt)))
+        print(f"params+opt bytes per device: {state_bytes} "
+              f"(one whole copy: {state_total})")
 
         data = ShardedTokenStream(DataConfig(
             vocab=cfg.vocab, seq_len=args.seq, global_batch=args.batch,
             seed=args.seed))
         it = PrefetchBuffer(data.shard(0, 0))
+        b_sh = named(shard_mod.batch_specs(
+            cfg, plan, api.train_specs(cfg, args.batch, args.seq)))
 
         step_fn = jax.jit(
             build_train_step(cfg, acfg, args.compress_grads),
@@ -134,8 +161,9 @@ def main(argv=None):
         detector = StragglerDetector()
 
         host = "host0"
+        losses = []
         for step in range(start, args.steps):
-            batch = next(it)
+            batch = jax.device_put(next(it), b_sh)
             t0 = time.time()
             loss, params, opt = step_fn(params, opt, batch)
             loss = float(loss)
@@ -143,7 +171,10 @@ def main(argv=None):
             detector.observe(host, dt)
             print(f"step {step:5d} loss {loss:8.4f} {dt*1e3:7.1f}ms")
             assert np.isfinite(loss), "loss diverged"
-            if (step + 1) % args.ckpt_every == 0 or step + 1 == args.steps:
+            losses.append(loss)
+            if args.ckpt_dir is not None and (
+                    (step + 1) % args.ckpt_every == 0
+                    or step + 1 == args.steps):
                 ckpt.save(args.ckpt_dir, step + 1, params,
                           extra={"arch": cfg.name, "loss": loss})
                 ckpt.save(Path(args.ckpt_dir) / "opt", step + 1, opt)
@@ -151,6 +182,12 @@ def main(argv=None):
         if stragglers:
             print(f"stragglers detected: {stragglers}")
     print("done")
+    return {"losses": losses, "state_bytes": state_bytes,
+            "state_total": state_total}
+
+
+def main(argv=None):
+    run(argv)
     return 0
 
 

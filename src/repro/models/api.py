@@ -11,6 +11,7 @@ to derive the sharding plan.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -180,8 +181,17 @@ def batch_axis_spec(init_fn):
     return jax.tree.map(one, a, b)
 
 
-def init(cfg: ModelConfig, key: jax.Array):
-    return init_params(lm.lm_schema(cfg), key)
+def init(cfg: ModelConfig, key: jax.Array, shardings=None):
+    """Random parameters for ``cfg``, drawn in one jitted program.
+
+    Under jit each leaf is drawn in float32 and cast in one fused pass, so
+    no float32 copy of a leaf is ever materialised: op by op, the last
+    ``(32, 4096, 11008)`` leaf of yi-6b needs a 5.8 GB float32 temporary
+    on top of the ~12 GB already placed.  ``shardings`` (a tree of
+    shardings matching the parameters) places every leaf where it is
+    made, so no device ever holds the whole model."""
+    return jax.jit(functools.partial(init_params, lm.lm_schema(cfg)),
+                   out_shardings=shardings)(key)
 
 
 def dims(cfg: ModelConfig):

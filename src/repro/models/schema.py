@@ -20,7 +20,12 @@ Initializer = Callable[[jax.Array, tuple[int, ...], Any], jax.Array]
 
 def normal(stddev: float = 0.02) -> Initializer:
     def init(key, shape, dtype):
-        return (jax.random.normal(key, shape, jnp.float32) * stddev).astype(dtype)
+        x = jax.random.normal(key, shape, jnp.float32)
+        # a no-op on f32 that XLA keeps: it stops the compiler folding
+        # ``stddev`` into the normal's own sqrt(2) factor under jit, so the
+        # jitted draw rounds exactly as the op-by-op one does
+        x = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=23)
+        return (x * stddev).astype(dtype)
     return init
 
 

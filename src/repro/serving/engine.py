@@ -2164,15 +2164,15 @@ class ServingEngine:
         # admit/park/steal property test).
         fold = b.home_list if b.home_list is not None \
             else self.sched.queues.global_queue()
-        # a member claimed into _pending (waiting out its steal stall) goes
-        # back into the bubble: the regenerated gang re-pushes it at its
-        # next burst, and leaving it pending too would double-schedule it
-        for s, t in list(self._pending.items()):
+        # a member claimed into _pending (waiting out its steal stall) keeps
+        # its claim, whose migration is already paid: a re-claim would
+        # steal and stall again, and a gang regenerated more often than one
+        # stall would never get a slot.  It leaves the bubble, so the
+        # regenerated gang cannot schedule it twice.
+        for t in self._pending.values():
             if t.parent is b:
-                del self._pending[s]
-                self._refund(s)               # reservation never spliced in
-                self.runtime.release(s, t, False, now)
-                fold.push(t)
+                b.children.remove(t)
+                t.parent = None
         n = 0
         for s in range(self.n_slots):
             req = self.slot_req[s]

@@ -18,12 +18,8 @@ Two seed bugs fixed in the elastic-fleet PR are pinned here:
 
 import itertools
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:                       # clean env: seeded-sampling shim
-    from _hypothesis_shim import given, settings
-    from _hypothesis_shim import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core import BubbleScheduler, bubble, novascale_16, thread
 from repro.distributed.fault_tolerance import (FleetSpec,

@@ -1,16 +1,7 @@
-"""Unit + property tests for the bubble scheduler core.
+"""Unit + property tests (``hypothesis``) for the bubble scheduler core."""
 
-The property tests prefer real `hypothesis`; in a clean environment they
-fall back to the deterministic shim in ``tests/_hypothesis_shim.py`` so
-tier-1 always collects and runs.
-"""
-
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:                       # clean env: seeded-sampling shim
-    from _hypothesis_shim import given, settings
-    from _hypothesis_shim import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core import (BubbleScheduler, QueueHierarchy, Topology, Level,
                         balanced_tree, bubble, novascale_16, numa_4x4_smt,

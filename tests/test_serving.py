@@ -14,12 +14,8 @@ slot, wrong-slot write) changes the stream and fails an equality assert.
 import numpy as np
 import pytest
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:                       # clean env: seeded-sampling shim
-    from _hypothesis_shim import given, settings
-    from _hypothesis_shim import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core.scheduler import StealCostModel
 from repro.serving import (SERVE_COST, ServingEngine, StubModelBackend,

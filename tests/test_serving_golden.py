@@ -34,7 +34,8 @@ ledger — per engine mode and topology, single-host and multi-host:
 * ``agentic_paged`` — a multi-turn session on the paged jax backend: the
   woken session's prefix KV pages are still resident, so every wake is a
   block-table re-point (``table_splices``) with **zero** pool copies and
-  no re-prefill.
+  no re-prefill.  Its float-model tokens depend on the JAX build, so its
+  ``streams`` entry pins paged == dense on the same trace, not a digest.
 
 Each snapshot records the engine step count, a digest of every completed
 request's full decode stream (the stub backend hashes token history, so
@@ -82,13 +83,16 @@ def _submit(eng: ServingEngine, spec, seed: int = 0) -> int:
     return n
 
 
+def _streams(eng: ServingEngine) -> list:
+    """Every completed request's decode stream, in request-id order."""
+    return sorted((r.rid, tuple(r.out_tokens)) for r in eng.completed)
+
+
 def _snapshot(eng: ServingEngine, n: int) -> dict:
     """Snapshot streams + ledger for a drained engine."""
     assert len(eng.completed) == n, (len(eng.completed), n)
-    digest = hashlib.blake2b(
-        repr(sorted((r.rid, tuple(r.out_tokens))
-                    for r in eng.completed)).encode(),
-        digest_size=8).hexdigest()
+    digest = hashlib.blake2b(repr(_streams(eng)).encode(),
+                             digest_size=8).hexdigest()
     c = eng.counters()
     snap = {"steps": eng.steps, "streams": digest}
     snap.update({k: c[k] for k in COUNTER_KEYS})
@@ -206,20 +210,33 @@ def simulate(case: str, variant: str) -> dict:
     if case == "agentic_paged":
         # a multi-turn session through the paged backend: both wakes find
         # the prefix KV pages resident — block-table re-points, zero pool
-        # copies, no re-prefill
+        # copies, no re-prefill.  The tokens of a float model depend on the
+        # JAX build, so in place of a digest the snapshot pins what the
+        # paged backend guarantees at float32 without ring wrap: its
+        # streams equal the dense backend's on the same trace
         import jax
         from repro.configs import get_config
         from repro.models import api
         from repro.serving import PagedJaxModelBackend
         cfg = get_config("yi-6b").reduced(vocab=97)
         params = api.init(cfg, jax.random.PRNGKey(0))
+
+        def session(backend) -> ServingEngine:
+            eng = ServingEngine(cfg, params, n_slots=4, cache_len=32,
+                                backend=backend)
+            rng = np.random.default_rng(7)
+            eng.submit(rng.integers(1, 97, 6), 10,
+                       tool_calls=((3, 4), (6, 3)))
+            eng.submit(rng.integers(1, 97, 5), 6)
+            return eng
+
         pb = PagedJaxModelBackend(cfg, params, 32, page_size=8)
-        eng = ServingEngine(cfg, params, n_slots=4, cache_len=32,
-                            backend=pb)
-        rng = np.random.default_rng(7)
-        eng.submit(rng.integers(1, 97, 6), 10, tool_calls=((3, 4), (6, 3)))
-        eng.submit(rng.integers(1, 97, 5), 6)
+        eng = session(pb)
         snap = _drive(eng, 2)
+        dense = session(None)               # default: JaxModelBackend
+        _drive(dense, 2)
+        assert _streams(eng) == _streams(dense)
+        snap["streams"] = "paged == dense"
         c = eng.counters()
         snap.update({k: c[k] for k in ("sleeps", "wakes",
                                        "wake_reprefills")})
@@ -260,7 +277,7 @@ GOLDEN = {
     ('open_loop', 'sla'): {'steps': 112, 'streams': '76c37afcead250e6', 'steals': 3, 'steal_refusals': 0, 'rebalances': 2, 'kv_migrations': 6, 'kv_page_moves': 3, 'kv_host_moves': 2, 'kv_parks': 6, 'prefills': 54, 'hbm_slot_waits': 0, 'hbm_refusals': 0, 'stall_steps': 29.375, 'preemptions': 4, 'preempt_parks': 6, 'demotions': 0},
     ('agentic_tool', 'hold'): {'steps': 36, 'streams': 'db5874ed0bb3a591', 'steals': 0, 'steal_refusals': 0, 'rebalances': 0, 'kv_migrations': 0, 'kv_page_moves': 0, 'kv_host_moves': 0, 'kv_parks': 0, 'prefills': 14, 'hbm_slot_waits': 0, 'hbm_refusals': 0, 'stall_steps': 0.0, 'sleeps': 0, 'holds': 10, 'wakes': 10, 'wake_home': 0, 'wake_away': 0, 'wake_reprefills': 0},
     ('agentic_tool', 'sleep'): {'steps': 28, 'streams': 'db5874ed0bb3a591', 'steals': 2, 'steal_refusals': 0, 'rebalances': 0, 'kv_migrations': 6, 'kv_page_moves': 5, 'kv_host_moves': 0, 'kv_parks': 10, 'prefills': 14, 'hbm_slot_waits': 0, 'hbm_refusals': 0, 'stall_steps': 2.875, 'sleeps': 10, 'holds': 0, 'wakes': 10, 'wake_home': 5, 'wake_away': 5, 'wake_reprefills': 0},
-    ('agentic_paged', 'paged'): {'steps': 14, 'streams': '38499d22f18a0589', 'steals': 0, 'steal_refusals': 0, 'rebalances': 0, 'kv_migrations': 0, 'kv_page_moves': 0, 'kv_host_moves': 0, 'kv_parks': 2, 'prefills': 2, 'hbm_slot_waits': 0, 'hbm_refusals': 0, 'stall_steps': 0.0, 'sleeps': 2, 'wakes': 2, 'wake_reprefills': 0, 'pool_copies': 0, 'table_splices': 2},
+    ('agentic_paged', 'paged'): {'steps': 14, 'streams': 'paged == dense', 'steals': 0, 'steal_refusals': 0, 'rebalances': 0, 'kv_migrations': 0, 'kv_page_moves': 0, 'kv_host_moves': 0, 'kv_parks': 2, 'prefills': 2, 'hbm_slot_waits': 0, 'hbm_refusals': 0, 'stall_steps': 0.0, 'sleeps': 2, 'wakes': 2, 'wake_reprefills': 0, 'pool_copies': 0, 'table_splices': 2},
 }
 
 

@@ -18,12 +18,8 @@ import math
 import numpy as np
 import pytest
 
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:                       # clean env: seeded-sampling shim
-    from _hypothesis_shim import given, settings
-    from _hypothesis_shim import strategies as st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core.bubble import reset_ids
 from repro.serving import (SLA_CLASSES, ServingEngine, StubModelBackend,
