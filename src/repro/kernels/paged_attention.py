@@ -52,8 +52,7 @@ NEG_INF = -1e30
 
 def _kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float,
-            window: Optional[int], page_size: int, npages: int,
-            K: int, hd: int):
+            window: Optional[int], page_size: int, npages: int):
     b = pl.program_id(0)
     ip = pl.program_id(1)
 
@@ -67,7 +66,7 @@ def _kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # while < lengths[b] (and, for SWA, within `window` of the query).  A
     # page past the slot's used count points at the trash page — every one
     # of its positions fails the length test, so its contents never leak.
-    g = q_ref.shape[2]
+    _, K, g, hd = q_ref.shape
     length = len_ref[b]
     k_pos = ip * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (g, page_size), 1)
@@ -121,7 +120,7 @@ def paged_attn(q, k_pool, v_pool, tables, lengths, *,
     from jax.experimental.pallas import tpu as pltpu
 
     kern = functools.partial(_kernel, scale=scale, window=window,
-                             page_size=page_size, npages=npages, K=K, hd=hd)
+                             page_size=page_size, npages=npages)
     page = pl.BlockSpec((1, page_size, K * hd),
                         lambda b, ip, tbl, ln: (tbl[b, ip], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
