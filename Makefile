@@ -20,7 +20,7 @@
 #   make golden-check regenerate the golden traces (simulator + serving
 #                     engine) and fail on any drift
 #   make bench        the full paper tables (slow: includes wall-clock
-#                     Table 1 and the roofline dry-run)
+#                     Table 1)
 
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))

@@ -42,7 +42,7 @@ Four kinds of row are gated:
   accidental O(n^2) splice — which costs 10x+, far outside any runner
   noise.  A tight band here would only train people to ignore the lane.
 
-Wall-clock rows (``us_per_call``, ``step_ms``) are reported but not gated
+Wall-clock rows (``us_per_call``) are reported but not gated
 — they are the only nondeterministic rows.  A gated baseline row that
 disappears from the current run also fails (a silently dropped benchmark
 is a regression in coverage).  New rows are allowed — commit a refreshed

@@ -1,12 +1,11 @@
-"""Benchmark aggregator: one module per paper table/figure + roofline.
+"""Benchmark aggregator: one module per paper table/figure.
 
 Prints ``name,value,derived`` CSV rows (value unit depends on the bench:
-us/call for Table 1, speedup for Table 2, gain-% for Fig 5, roofline step
-ms for the dry-run table).
+us/call for Table 1, speedup for Table 2, gain-% for Fig 5).
 
 ``--smoke`` runs a seconds-scale subset (conduction-only Table 2 with the
 imbalanced + thrash stealing sections, small Fig 5 sizes, the stub-model
-serving-gang rows, no wall-clock Table 1 / roofline) — the CI sanity
+serving-gang rows, no wall-clock Table 1) — the CI sanity
 target — and writes a machine-readable ``BENCH_smoke.json`` (override the
 path with ``--json PATH``; pass ``--json`` in non-smoke mode to capture
 the full run).  Schema::
@@ -14,7 +13,7 @@ the full run).  Schema::
     {"schema": 1, "suite": "smoke"|"full",
      "rows": [{"name": "table2/thrash_adaptive", "value": 10.26,
                "kind": "speedup"|"gain_pct"|"latency"|"throughput"
-                       |"us_per_call"|"step_ms",
+                       |"us_per_call",
                "derived": "...",
                "counters": {"steals": ..., "steals_by_level": {...},
                             "rebalances": ..., "steal_cost": ...}}]}
@@ -45,7 +44,7 @@ sys.path.insert(0, os.path.join(_ROOT, "src"))
 
 # value unit per benchmark module (JSON row "kind")
 _KINDS = {"table1": "us_per_call", "table2": "speedup", "fig5": "gain_pct",
-          "roofline": "step_ms", "serve": "speedup"}
+          "serve": "speedup"}
 
 
 def _json_path(argv: list[str], smoke: bool):
@@ -68,8 +67,8 @@ def main() -> None:
         mods = [table2_conduction, fig5_fibonacci, serve_gangs,
                 serve_open_loop, serve_elastic, serve_agentic]
     else:
-        from benchmarks import roofline, table1_cost
-        mods = [table1_cost, table2_conduction, fig5_fibonacci, roofline,
+        from benchmarks import table1_cost
+        mods = [table1_cost, table2_conduction, fig5_fibonacci,
                 serve_gangs, serve_open_loop, serve_elastic, serve_agentic]
 
     failed = 0

@@ -10,13 +10,24 @@ bursts, sinks, steals, regenerations — with timestamps and queue levels.
 ``timeline()`` renders a per-cpu ASCII gantt; ``locality_report()``
 aggregates where each bubble's threads actually ran versus where their
 data lives (the check the paper wants: did the strategy keep affinity?).
+The Tracer's clock is the scheduler's logical one, not a time source.
+
+:class:`SpanLog` is the time source: the serving program's phases
+(``engine.step`` and the schedule, prefill, splice, decode, retire and
+extract phases inside it, and the paged backend's own steps) as spans on
+the host clock.  Nothing is recorded until a log is attached
+(:func:`attach`); detached, :func:`span` hands back one shared no-op
+context and reads no clock.  Attached, each span is also a profiler
+annotation ``repro.<name>``, which puts it on the device trace's clock
+when a profile is being captured.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import defaultdict
-from typing import Optional
+from typing import Callable, Optional
 
 from .bubble import Bubble, Thread
 from .scheduler import BubbleScheduler
@@ -165,3 +176,136 @@ class Tracer:
         for e in self.events:
             kinds[e.kind] += 1
         return dict(kinds)
+
+
+# ---------------------------------------------------------------------------
+# spans of the serving program's phases
+# ---------------------------------------------------------------------------
+
+class SpanLog:
+    """The program's spans, kept in memory until the caller writes them.
+
+    ``records`` holds one ``(name, t0, t1, parent, info)`` per span, in
+    the order the spans opened: ``t0``/``t1`` on ``clock`` (seconds),
+    ``parent`` the index in ``records`` of the enclosing span (None at the
+    root), ``info`` the dict the span's ``with`` binds, which the code
+    inside fills (request ids as ``rid``/``rids``, counts).  A compile or
+    a persistent-cache load that happens inside a span is counted on the
+    innermost open one, as ``info["compiles"]`` (programs XLA compiled)
+    and ``info["cache_loads"]`` (programs read back from the cache).
+
+    Each span is also a ``jax.profiler.TraceAnnotation("repro." + name)``
+    (no arguments: ``info`` stays in memory), so that a profile being
+    captured holds the spans on the device's clock; with no profile
+    being captured the annotation records nothing.  Spans are opened and
+    closed by one thread, the engine's.
+    """
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter):
+        import jax
+        self.clock = clock
+        self.records: list[tuple] = []
+        self._open: list[int] = []        # indices of the open spans
+        self._annotation = jax.profiler.TraceAnnotation
+
+    def _count(self, key: str, n: int) -> None:
+        if self._open:
+            info = self.records[self._open[-1]][4]
+            info[key] = info.get(key, 0) + n
+
+
+class _Span:
+    __slots__ = ("log", "name", "info", "i", "t0", "ann")
+
+    def __init__(self, log: SpanLog, name: str, info: dict):
+        self.log, self.name, self.info = log, name, info
+
+    def __enter__(self) -> dict:
+        log = self.log
+        self.i = len(log.records)
+        log.records.append((self.name, None, None,
+                            log._open[-1] if log._open else None,
+                            self.info))
+        log._open.append(self.i)
+        self.ann = log._annotation("repro." + self.name)
+        self.ann.__enter__()
+        self.t0 = log.clock()
+        return self.info
+
+    def __exit__(self, *exc) -> bool:
+        log = self.log
+        t1 = log.clock()
+        self.ann.__exit__(*exc)
+        log._open.pop()
+        name, _, _, parent, info = log.records[self.i]
+        log.records[self.i] = (name, self.t0, t1, parent, info)
+        return False
+
+
+class _NoSpan:
+    """What :func:`span` returns with no log attached: binds None."""
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+_log: Optional[SpanLog] = None
+_listening = False
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def span(name: str, **info):
+    """A span of the attached log around a ``with`` block, which binds
+    its ``info`` dict; with no log attached, the shared no-op context,
+    which binds None (callers fill ``info`` only when it is not None)."""
+    log = _log
+    if log is None:
+        return _NO_SPAN
+    return _Span(log, name, info)
+
+
+def attach(log: SpanLog) -> None:
+    """Record the program's spans into ``log`` from now on."""
+    global _log
+    _listen()
+    _log = log
+
+
+def detach() -> Optional[SpanLog]:
+    """Stop recording; returns the log that was attached."""
+    global _log
+    log, _log = _log, None
+    return log
+
+
+def _listen() -> None:
+    """Count compiles and cache loads on the innermost open span: one
+    pair of ``jax.monitoring`` listeners per process, idle while no log
+    is attached.  JAX reports a compile event for every program it
+    builds, also one it reads back from the persistent cache, and a
+    cache hit inside that event; a hit is therefore a load, not a
+    compile."""
+    global _listening
+    if _listening:
+        return
+    from jax import monitoring
+
+    def on_event(event, **kw):
+        if event == CACHE_HIT_EVENT and _log is not None:
+            _log._count("cache_loads", 1)
+            _log._count("compiles", -1)
+
+    def on_duration(event, secs, **kw):
+        if event == COMPILE_EVENT and _log is not None:
+            _log._count("compiles", 1)
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    _listening = True

@@ -113,26 +113,30 @@ def make_loss_fn(cfg: ModelConfig, use_kernel: bool = False,
     return loss
 
 
+# The step functions carry stable names: a jitted one's program is
+# ``jit_<name>`` in a profiler trace (``jit_paged_decode`` for the served
+# decode), which is how the trace's readers find its device time.
+
 def make_prefill_fn(cfg: ModelConfig, cache_len: int,
                     use_kernel: bool = False):
     if cfg.enc_layers:
-        def pf(params, batch):
+        def prefill(params, batch):
             return lm.encdec_prefill(params, batch, cfg, cache_len)
-        return pf
-    def pf(params, batch):
+        return prefill
+    def prefill(params, batch):
         return lm.prefill(params, batch, cfg, cache_len,
                           use_kernel=use_kernel)
-    return pf
+    return prefill
 
 
 def make_decode_fn(cfg: ModelConfig):
     if cfg.enc_layers:
-        def step(params, token, states, enc):
+        def decode(params, token, states, enc):
             return lm.encdec_decode_step(params, token, states, enc, cfg)
-        return step
-    def step(params, token, states):
+        return decode
+    def decode(params, token, states):
         return lm.decode_step(params, token, states, cfg)
-    return step
+    return decode
 
 
 def make_paged_decode_fn(cfg: ModelConfig, use_kernel: bool = False):
@@ -140,10 +144,10 @@ def make_paged_decode_fn(cfg: ModelConfig, use_kernel: bool = False):
     lengths) -> (logits, states)``.  See ``models.paged``."""
     from . import paged
 
-    def step(params, token, states, tables, lengths):
+    def paged_decode(params, token, states, tables, lengths):
         return paged.decode_step(params, token, states, tables, lengths,
                                  cfg, use_kernel=use_kernel)
-    return step
+    return paged_decode
 
 
 def batch_axis_spec(init_fn):

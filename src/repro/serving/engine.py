@@ -75,6 +75,7 @@ from repro.core.policies import BubblePolicy, StealPolicy
 from repro.core.runtime import SchedulerRuntime
 from repro.core.scheduler import StealCostModel
 from repro.core.topology import Level, Topology
+from repro.core.trace import span
 
 from .workload import goodput_under_sla, percentile
 
@@ -588,24 +589,32 @@ class PagedJaxModelBackend:
         C = self._lm._cache_len(self.cfg, self.cache_len)
         assert S <= C, \
             f"paged prefill keeps the whole prompt resident ({S} > {C})"
-        logits, st = self._prefill(self.params,
-                                   {"tokens": jnp.asarray(np.stack(prompts))})
-        toks = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        return [(int(toks[i]), self._fresh_handle(st, i, S))
-                for i in range(len(prompts))]
+        with span("prefill.forward"):
+            logits, st = self._prefill(
+                self.params, {"tokens": jnp.asarray(np.stack(prompts))})
+        with span("prefill.readback"):
+            toks = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        with span("prefill.handles"):
+            return [(int(toks[i]), self._fresh_handle(st, i, S))
+                    for i in range(len(prompts))]
 
     # -- decode ---------------------------------------------------------------
     def decode(self, tokens: np.ndarray, shard: _PagedShard
                ) -> tuple[np.ndarray, object]:
         jnp = self._jax.numpy
-        self._ensure_pages(shard)
-        logits, shard.states = self._decode(
-            self.params, jnp.asarray(tokens), shard.states,
-            jnp.asarray(shard.table), jnp.asarray(shard.lengths))
+        with span("decode.prep"):
+            self._ensure_pages(shard)
+            tokens = jnp.asarray(tokens)
+            table = jnp.asarray(shard.table)
+            lengths = jnp.asarray(shard.lengths)
+        with span("decode.launch"):
+            logits, shard.states = self._decode(
+                self.params, tokens, shard.states, table, lengths)
         # every slot's position advances, occupied or not — the host-side
         # mirror of the dense path's ``pos + 1`` for the whole batch
         shard.lengths = shard.lengths + 1
-        next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        with span("decode.readback"):
+            next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         return next_tok, shard
 
     # -- splice / extract: migration as metadata ------------------------------
@@ -668,32 +677,37 @@ class PagedJaxModelBackend:
             shard.lengths[slot] = h["length"]
             for key, tree in h["leaves"].items():
                 leaf_writes.setdefault(key, []).append((slot, tree))
-        # apply the queued fresh-prefill page-ins: ONE scatter per layer
-        for (si, pi), entries in pool_pages.items():
-            pool = shard.states[si][pi]
-            idx = jnp.asarray([p for pages, _, _ in entries for p in pages])
-            kcat = jnp.concatenate([k for _, k, _ in entries], axis=1)
-            vcat = jnp.concatenate([v for _, _, v in entries], axis=1)
-            new_stage = list(shard.states[si])
-            new_stage[pi] = self._paged.PagedKV(
-                k=pool.k.at[:, idx].set(kcat.astype(pool.k.dtype)),
-                v=pool.v.at[:, idx].set(vcat.astype(pool.v.dtype)))
-            shard.states[si] = tuple(new_stage)
-        # batch-axis leaves (recurrent states): one traversal per layer
-        for (si, pi), entries in leaf_writes.items():
-            slots = jnp.asarray([s for s, _ in entries])
+        with span("splice.page_in") as sp:
+            if sp is not None:
+                sp["pages"] = sum(len(pages) for pages, _, _ in
+                                  next(iter(pool_pages.values()), ()))
+            # apply the queued fresh-prefill page-ins: ONE scatter per layer
+            for (si, pi), entries in pool_pages.items():
+                pool = shard.states[si][pi]
+                idx = jnp.asarray([p for pages, _, _ in entries
+                                   for p in pages])
+                kcat = jnp.concatenate([k for _, k, _ in entries], axis=1)
+                vcat = jnp.concatenate([v for _, _, v in entries], axis=1)
+                new_stage = list(shard.states[si])
+                new_stage[pi] = self._paged.PagedKV(
+                    k=pool.k.at[:, idx].set(kcat.astype(pool.k.dtype)),
+                    v=pool.v.at[:, idx].set(vcat.astype(pool.v.dtype)))
+                shard.states[si] = tuple(new_stage)
+            # batch-axis leaves (recurrent states): one traversal per layer
+            for (si, pi), entries in leaf_writes.items():
+                slots = jnp.asarray([s for s, _ in entries])
 
-            def write(ax, b, *ones):
-                if ax < 0:
-                    return b
-                idx = (slice(None),) * ax + (slots,)
-                return b.at[idx].set(jnp.concatenate(ones, axis=ax))
+                def write(ax, b, *ones):
+                    if ax < 0:
+                        return b
+                    idx = (slice(None),) * ax + (slots,)
+                    return b.at[idx].set(jnp.concatenate(ones, axis=ax))
 
-            new_stage = list(shard.states[si])
-            new_stage[pi] = self._jax.tree.map(
-                write, self._paged_axes[si][pi], shard.states[si][pi],
-                *[t for _, t in entries])
-            shard.states[si] = tuple(new_stage)
+                new_stage = list(shard.states[si])
+                new_stage[pi] = self._jax.tree.map(
+                    write, self._paged_axes[si][pi], shard.states[si][pi],
+                    *[t for _, t in entries])
+                shard.states[si] = tuple(new_stage)
         return shard
 
     def extract(self, shard: _PagedShard, slot: int):
@@ -1573,6 +1587,52 @@ class ServingEngine:
         # (exec group, prompt len) -> [(slot, req)]: fresh prompts grouped
         # into one wave-batched prefill call per host per length
         fresh: dict[tuple[int, int], list] = {}
+        with span("engine.schedule") as sp:
+            mark = self._sched_mark() if sp is not None else None
+            self._claim(now, writes, fresh, sp)
+            if sp is not None:
+                self._sched_note(sp, mark)
+        # wave-batched prefill: the per-request loop this replaces ran one
+        # model call per fresh prompt; the splice below was already batched
+        for (_, length), batch in fresh.items():
+            with span("engine.prefill") as sp:
+                if sp is not None:
+                    sp.update(rids=[req.rid for _, req in batch],
+                              n=len(batch), length=length)
+                results = self.backend.prefill_wave(
+                    [req.prompt for _, req in batch])
+                self.stats.prefill_waves += 1
+                for (slot, req), (tok, st) in zip(batch, results):
+                    req.out_tokens.append(tok)
+                    self._note_first_token(req, now)
+                    self.tokens[slot, 0] = tok
+                    self.stats.prefills += 1
+                    writes.append((slot, st))
+        if writes:
+            # one batched splice per host batch (execution group): each
+            # group's KV shard is written in a single traversal
+            by_group: dict[int, list[tuple[int, object]]] = {}
+            for slot, st in writes:
+                g = self._group_of[slot]
+                lo = self._exec_groups[g][0]
+                by_group.setdefault(g, []).append((slot - lo, st))
+            for g, pairs in by_group.items():
+                with span("engine.splice") as sp:
+                    if sp is not None:
+                        lo = self._exec_groups[g][0]
+                        sp.update(rids=[self.slot_req[lo + s].rid
+                                        for s, _ in pairs], n=len(pairs))
+                    self._states[g] = self.backend.splice(self._states[g],
+                                                          pairs)
+                self.stats.kv_splices += 1
+            self.stats.kv_spliced_slots += len(writes)
+
+    def _claim(self, now: float, writes: list, fresh: dict,
+               sp: Optional[dict]) -> None:
+        """``_admit``'s claim loop: each free slot asks the runtime for
+        work; a parked request queues its splice in ``writes``, a fresh
+        one its prefill in ``fresh``.  ``sp`` (a span's info, or None)
+        collects the claimed request ids."""
         # SLA gate: one WDRR replenish per admission wave; the resulting
         # eligible-class set rides the covering-list walk as a task filter
         # and is spent/recomputed in place as the wave's slots admit
@@ -1640,6 +1700,8 @@ class ServingEngine:
             req: Request = t.request                      # type: ignore
             self.slot_req[slot] = req
             self.slot_thread[slot] = t
+            if sp is not None:
+                sp.setdefault("rids", []).append(req.rid)
             # data policy: first/next-touch homing of the gang's KV pages
             self.runtime.touch(slot, t)
             parked = self._kv_park.pop(req.rid, None)
@@ -1654,36 +1716,16 @@ class ServingEngine:
                 key = (self._group_of[slot], len(req.prompt))
                 fresh.setdefault(key, []).append((slot, req))
             else:
-                tok, st = self.backend.prefill(req.prompt)
+                with span("engine.prefill") as sp:
+                    if sp is not None:
+                        sp.update(rids=[req.rid], n=1,
+                                  length=len(req.prompt))
+                    tok, st = self.backend.prefill(req.prompt)
                 req.out_tokens.append(tok)
                 self._note_first_token(req, now)
                 self.tokens[slot, 0] = tok
                 self.stats.prefills += 1
                 writes.append((slot, st))
-        # wave-batched prefill: the per-request loop this replaces ran one
-        # model call per fresh prompt; the splice below was already batched
-        for (_, _), batch in fresh.items():
-            results = self.backend.prefill_wave(
-                [req.prompt for _, req in batch])
-            self.stats.prefill_waves += 1
-            for (slot, req), (tok, st) in zip(batch, results):
-                req.out_tokens.append(tok)
-                self._note_first_token(req, now)
-                self.tokens[slot, 0] = tok
-                self.stats.prefills += 1
-                writes.append((slot, st))
-        if writes:
-            # one batched splice per host batch (execution group): each
-            # group's KV shard is written in a single traversal
-            by_group: dict[int, list[tuple[int, object]]] = {}
-            for slot, st in writes:
-                g = self._group_of[slot]
-                lo = self._exec_groups[g][0]
-                by_group.setdefault(g, []).append((slot - lo, st))
-            for g, pairs in by_group.items():
-                self._states[g] = self.backend.splice(self._states[g], pairs)
-                self.stats.kv_splices += 1
-            self.stats.kv_spliced_slots += len(writes)
 
     def _evict(self, slot: int, now: float) -> None:
         req = self.slot_req[slot]
@@ -1739,10 +1781,12 @@ class ServingEngine:
         t = self.slot_thread.pop(slot)
         self.slot_req[slot] = None
         g = self._group_of[slot]
-        self._kv_park[req.rid] = (
-            self.backend.extract(self._states[g],
-                                 slot - self._exec_groups[g][0]),
-            int(self.tokens[slot, 0]))
+        with span("engine.extract") as sp:
+            if sp is not None:
+                sp["rid"] = req.rid
+            handle = self.backend.extract(self._states[g],
+                                          slot - self._exec_groups[g][0])
+        self._kv_park[req.rid] = (handle, int(self.tokens[slot, 0]))
         self.stats.kv_parks += 1
         self.tokens[slot, 0] = 0
         self._refund(slot)    # parked KV lives host-side, off the budget
@@ -2066,22 +2110,43 @@ class ServingEngine:
         empty this step skips the call entirely.  Slots are independent in
         every backend, so the union of per-host calls decodes exactly what
         one global call would — sharding execution models per-shard
-        latency without touching the streams."""
+        latency without touching the streams.
+
+        With a span log attached (``repro.core.trace``) the step is the
+        root span ``engine.step``; its phases are ``engine.schedule``,
+        ``engine.prefill``, ``engine.splice``, ``engine.decode``,
+        ``engine.retire`` and ``engine.extract``, whose ``info`` names
+        the requests they touched."""
+        with span("engine.step") as sp:
+            live = self._step()
+            if sp is not None:
+                sp.update(step=self.steps - 1, live=live)
+            return live
+
+    def _step(self) -> int:
         now = float(self.steps)
         self.steps += 1
         if self.kv_store is not None:
             self._maybe_snapshot_kv(int(now))
-        if self._sleeping or self._thinking:
-            # tool responses land before admission, so a woken session can
-            # re-enter a slot (and decode) in the very step it wakes
-            self._process_wakes(now)
-        self._maybe_rebalance(now)
-        self._maybe_preempt(now)
+        with span("engine.schedule") as sp:
+            mark = self._sched_mark() if sp is not None else None
+            if self._sleeping or self._thinking:
+                # tool responses land before admission, so a woken session
+                # can re-enter a slot (and decode) in the very step it wakes
+                self._process_wakes(now)
+            self._maybe_rebalance(now)
+            self._maybe_preempt(now)
+            if sp is not None:
+                self._sched_note(sp, mark)
         self._admit(now)
         # after admission, so the ledger reflects what actually occupies
         # each group — a pre-admission check would quote deficits against
         # reservations the same wave's claims are about to take
-        self._maybe_split_gang(now)
+        with span("engine.schedule") as sp:
+            mark = self._sched_mark() if sp is not None else None
+            self._maybe_split_gang(now)
+            if sp is not None:
+                self._sched_note(sp, mark)
         active = [s for s in range(self.n_slots)
                   if self.slot_req[s] is not None
                   and s not in self._thinking]
@@ -2107,26 +2172,50 @@ class ServingEngine:
                 self.stats.host_skipped_steps[g] += 1
                 continue                     # slow host: decode not done yet
             self._host_credit[g] -= 1.0
-            next_tok, self._states[g] = self.backend.decode(
-                self.tokens[lo:hi], self._states[g])
+            with span("engine.decode") as sp:
+                if sp is not None:
+                    sp["rids"] = [self.slot_req[s].rid for s in active_g]
+                next_tok, self._states[g] = self.backend.decode(
+                    self.tokens[lo:hi], self._states[g])
             self.stats.host_decode_steps[g] += 1
             self.stats.host_active_slots[g] += len(active_g)
-            for s in active_g:
-                self.tokens[s, 0] = next_tok[s - lo]
-                req = self.slot_req[s]
-                req.out_tokens.append(int(next_tok[s - lo]))
-                self._note_token(req, now)
-                t = self.slot_thread[s]
-                t.remaining -= 1.0
-                if len(req.out_tokens) >= req.max_new_tokens:
-                    self._evict(s, now)
-                elif (req.next_call < len(req.tool_calls)
-                      and len(req.out_tokens)
-                      >= req.tool_calls[req.next_call][0]):
-                    self._tool_call(s, now)
-                else:
-                    self._maybe_demote(req, t)
+            with span("engine.retire") as sp:
+                for s in active_g:
+                    self.tokens[s, 0] = next_tok[s - lo]
+                    req = self.slot_req[s]
+                    req.out_tokens.append(int(next_tok[s - lo]))
+                    self._note_token(req, now)
+                    t = self.slot_thread[s]
+                    t.remaining -= 1.0
+                    if len(req.out_tokens) >= req.max_new_tokens:
+                        if sp is not None:
+                            sp.setdefault("rids", []).append(req.rid)
+                        self._evict(s, now)
+                    elif (req.next_call < len(req.tool_calls)
+                          and len(req.out_tokens)
+                          >= req.tool_calls[req.next_call][0]):
+                        self._tool_call(s, now)
+                    else:
+                        self._maybe_demote(req, t)
         return len(active)
+
+    def _sched_mark(self) -> tuple:
+        """What a schedule span's ``info`` is measured against."""
+        s = self.sched.stats
+        return (s.steals, s.steal_attempts, s.rebalance_moves,
+                set(self._kv_park))
+
+    def _sched_note(self, info: dict, mark: tuple) -> None:
+        """A schedule span's ``info``: the scheduler's steals, steal
+        attempts and rebalance moves in it, and the requests it parked
+        (preempted or regenerated)."""
+        s = self.sched.stats
+        info["steals"] = s.steals - mark[0]
+        info["steal_attempts"] = s.steal_attempts - mark[1]
+        info["rebalance_moves"] = s.rebalance_moves - mark[2]
+        parked = [rid for rid in self._kv_park if rid not in mark[3]]
+        if parked:
+            info["parked"] = parked
 
     def _drained(self) -> bool:
         return (not any(self.slot_req) and not self._pending
@@ -2197,10 +2286,12 @@ class ServingEngine:
                     n += 1
                     continue
                 g = self._group_of[s]
-                self._kv_park[req.rid] = (
-                    self.backend.extract(self._states[g],
-                                         s - self._exec_groups[g][0]),
-                    int(self.tokens[s, 0]))
+                with span("engine.extract") as sp:
+                    if sp is not None:
+                        sp["rid"] = req.rid
+                    handle = self.backend.extract(
+                        self._states[g], s - self._exec_groups[g][0])
+                self._kv_park[req.rid] = (handle, int(self.tokens[s, 0]))
                 self.stats.kv_parks += 1
                 self.tokens[s, 0] = 0
                 self._refund(s)   # parked KV lives host-side, off the budget
@@ -2236,8 +2327,11 @@ class ServingEngine:
         t = self.slot_thread.pop(slot)
         self.slot_req[slot] = None
         g = self._group_of[slot]
-        handle = self.backend.extract(self._states[g],
-                                      slot - self._exec_groups[g][0])
+        with span("engine.extract") as sp:
+            if sp is not None:
+                sp["rid"] = req.rid
+            handle = self.backend.extract(self._states[g],
+                                          slot - self._exec_groups[g][0])
         entry = SleepEntry(req.rid, t, handle, int(self.tokens[slot, 0]),
                            self.topo.cpus[slot].path()[self._page_idx],
                            int(now), wake_at)
@@ -2299,8 +2393,11 @@ class ServingEngine:
         e = self._thinking.pop(slot)
         req = self.slot_req[slot]
         g = self._group_of[slot]
-        self._states[g] = self.backend.splice(
-            self._states[g], [(slot - self._exec_groups[g][0], e.state)])
+        with span("engine.splice") as sp:
+            if sp is not None:
+                sp.update(rids=[req.rid], n=1)
+            self._states[g] = self.backend.splice(
+                self._states[g], [(slot - self._exec_groups[g][0], e.state)])
         self.stats.kv_splices += 1
         self.stats.kv_spliced_slots += 1
         self.tokens[slot, 0] = e.token
@@ -2369,7 +2466,10 @@ class ServingEngine:
             m = len(req.out_tokens)
             hist = req.prompt if m == 1 else np.concatenate(
                 [req.prompt, np.asarray(req.out_tokens[:-1], np.int32)])
-            _, st = self.backend.prefill(hist)
+            with span("engine.prefill") as sp:
+                if sp is not None:
+                    sp.update(rids=[req.rid], n=1, length=len(hist))
+                _, st = self.backend.prefill(hist)
             tok = int(req.out_tokens[-1])
             debt = (len(req.prompt) + m - 1) * self.reprefill_unit
             if debt:

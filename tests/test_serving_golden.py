@@ -61,7 +61,7 @@ import hashlib
 import numpy as np
 import pytest
 
-from repro.core import reset_ids
+from repro.core import reset_ids, trace
 from repro.core.scheduler import StealCostModel  # noqa: F401  (re-export)
 from repro.serving import (FLAT_SERVE_COST, SERVE_COST, ServingEngine,
                            StubModelBackend)
@@ -286,6 +286,21 @@ def test_serving_golden_trace(case: str, variant: str):
     got = simulate(case, variant)
     want = GOLDEN[(case, variant)]
     assert got == want, (case, variant, got, want)
+
+
+@pytest.mark.parametrize("case,variant", CASES)
+def test_serving_golden_trace_with_span_log_attached(case: str,
+                                                     variant: str):
+    """An attached span log (``repro.core.trace``) records the engine's
+    phases and changes nothing it serves: every golden still holds."""
+    log = trace.SpanLog()
+    trace.attach(log)
+    try:
+        got = simulate(case, variant)
+    finally:
+        trace.detach()
+    assert got == GOLDEN[(case, variant)], (case, variant, got)
+    assert log.records and log.records[0][0] == "engine.step"
 
 
 def test_mode_never_changes_streams():
