@@ -343,7 +343,7 @@ class JaxModelBackend:
         self.params = params
         self.cache_len = cache_len
         self._decode = jax.jit(api.make_decode_fn(cfg))
-        self._prefill = api.make_prefill_fn(cfg, cache_len)
+        self._prefill = jax.jit(api.make_prefill_fn(cfg, cache_len))
         self._axes = api.batch_axis_spec(
             lambda n: api.lm.init_state(cfg, n, cache_len))
 
@@ -503,7 +503,7 @@ class PagedJaxModelBackend:
         self.page_bytes = paged.kv_page_bytes(cfg, page_size)
         self.use_kernel = use_kernel
         self._decode = jax.jit(api.make_paged_decode_fn(cfg, use_kernel))
-        self._prefill = api.make_prefill_fn(cfg, cache_len)
+        self._prefill = jax.jit(api.make_prefill_fn(cfg, cache_len))
         self._dense_axes = api.batch_axis_spec(
             lambda n: lm.init_state(cfg, n, cache_len))
         self._paged_axes = api.batch_axis_spec(

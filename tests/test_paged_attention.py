@@ -28,7 +28,16 @@ Four layers, cheapest first:
   as a block-table re-point (``table_splices > 0``, ``pool_copies == 0``,
   no re-prefill), and its stream is bit-identical to a cold wake whose KV
   was stale-evicted and re-prefilled from the token history.
+* **jitted prefill** — a second admission wave of one shape compiles
+  nothing and loads nothing from the compile cache, and serves what the
+  model's prefill run op by op serves.
 """
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -286,3 +295,68 @@ class TestBatchAxisSpec:
             leaves = jax.tree.leaves(axes)
             assert leaves and all(a in (-1, 0, 1) for a in leaves), \
                 (arch, leaves)
+
+
+PREFILL_CHILD = """
+import json, numpy as np, jax, jax.numpy as jnp
+from repro.configs import get_config
+from repro.core import trace
+from repro.models import api
+from repro.serving import PagedJaxModelBackend
+jax.config.update("jax_compilation_cache_dir", {cache!r})
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+cfg = get_config({arch!r}).reduced(vocab=97, param_dtype="bfloat16",
+                                   compute_dtype="bfloat16")
+params = api.init(cfg, jax.random.PRNGKey(0))
+pb = PagedJaxModelBackend(cfg, params, 32, page_size=8)
+rng = np.random.default_rng(3)
+prompts = [rng.integers(1, 97, 12) for _ in range(2)]
+log = trace.SpanLog()
+trace.attach(log)
+pb.prefill_wave(prompts)
+served = pb.prefill_wave(prompts)        # the same (wave size, length)
+trace.detach()
+logits, st = api.make_prefill_fn(cfg, 32)(
+    params, {{"tokens": jnp.asarray(np.stack(prompts))}})
+gap = scale = 0.0
+for i, (_, h) in enumerate(served):
+    want = pb._fresh_handle(st, i, 12)
+    pairs = zip(jax.tree.leaves((h["kv"], h["leaves"])),
+                jax.tree.leaves((want["kv"], want["leaves"])))
+    for a, b in pairs:
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        gap = max(gap, float(np.abs(a - b).max()))
+        scale = max(scale, float(np.abs(b).max()))
+print(json.dumps({{
+    "forward": [r[4] for r in log.records if r[0] == "prefill.forward"],
+    "tokens": [t for t, _ in served],
+    "eager": np.asarray(jnp.argmax(logits, axis=-1)).tolist(),
+    "gap": gap, "scale": scale}}))
+"""
+
+
+class TestJittedPrefill:
+    @pytest.mark.parametrize("arch", ["yi-6b", "rwkv6-3b"])
+    def test_second_wave_of_a_shape_neither_compiles_nor_loads(
+            self, arch, tmp_path):
+        """An eager prefill re-traces its layer scan on every wave and
+        reads the program back from the compile cache; the jitted one
+        finds it in memory.  In a child: the compile cache is process-wide
+        JAX config."""
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=str(Path(__file__).resolve().parents[1]
+                                  / "src"))
+        r = subprocess.run(
+            [sys.executable, "-c",
+             PREFILL_CHILD.format(cache=str(tmp_path), arch=arch)],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        got = json.loads(r.stdout.strip().splitlines()[-1])
+        first, again = got["forward"]
+        assert first.get("compiles", 0) >= 1       # the counter is live
+        assert again.get("compiles", 0) == 0
+        assert again.get("cache_loads", 0) == 0
+        assert got["tokens"] == got["eager"]
+        assert got["scale"] > 0
+        assert got["gap"] <= 2 ** -6 * max(got["scale"], 1.0)

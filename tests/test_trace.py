@@ -222,6 +222,24 @@ def test_the_served_decode_program_is_named_paged_decode():
     assert text.startswith("module @jit_paged_decode")
 
 
+@pytest.mark.parametrize("paged", [True, False])
+def test_the_served_prefill_program_is_named_prefill(paged):
+    """Both backends serve the prefill as one jitted program,
+    ``jit_prefill``, so its device time can be found by name."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.serving import JaxModelBackend, PagedJaxModelBackend
+    cfg = get_config("yi-6b").reduced(vocab=97)
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    backend = (PagedJaxModelBackend(cfg, params, 32, page_size=8) if paged
+               else JaxModelBackend(cfg, params, 32))
+    text = backend._prefill.lower(
+        backend.params, {"tokens": jnp.ones((2, 8), jnp.int32)}).as_text()
+    assert text.startswith("module @jit_prefill")
+
+
 CHILD = """
 import json, jax, jax.numpy as jnp
 from repro.core import trace
