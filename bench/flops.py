@@ -17,11 +17,22 @@ no kernel's bound.  Bytes are what a decode step must move at least:
 every weight once, the embedding rows it gathers, and per live sequence
 its KV (read up to its length, one position written) or its recurrent
 state (read and written).
+
+A block kind not listed here, or an expert FFN (``n_experts``), raises
+``Uncounted``: such a configuration names a count of its own in its file
+(``"count": "<name>"``, ``bench/counts/<name>.py``, with this module's
+``decode_flops`` and ``decode_bytes``, raising ``bench.flops.Uncounted``
+for what it cannot count), and the readers that count leave their metric
+out of a run whose count raises it.  Any other error ends the run.
 """
 
 from __future__ import annotations
 
 LORA = 64           # the rwkv decay's rank (the program's rwkv6_schema)
+
+
+class Uncounted(KeyError):
+    """A count met a block kind or a layer it has no count for."""
 
 
 def head_dim(cfg: dict) -> int:
@@ -31,6 +42,9 @@ def head_dim(cfg: dict) -> int:
 
 
 def kinds(cfg: dict) -> list[str]:
+    if cfg.get("n_experts"):
+        raise Uncounted("flops: no count for an expert FFN (n_experts); "
+                        "the configuration names a count of its own")
     pat = list(cfg.get("block_pattern", ["attn"]))
     return [pat[i % len(pat)] for i in range(cfg["n_layers"])]
 
@@ -42,7 +56,7 @@ def layer_matmul_weights(cfg: dict, kind: str) -> int:
         return d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * f
     if kind == "rwkv":
         return 5 * d * d + 2 * d * LORA + 2 * d * f + d * d
-    raise KeyError(f"flops: no count for block kind {kind!r}")
+    raise Uncounted(f"flops: no count for block kind {kind!r}")
 
 
 def layer_other_weight_bytes(cfg: dict, kind: str) -> int:
@@ -53,7 +67,7 @@ def layer_other_weight_bytes(cfg: dict, kind: str) -> int:
     if kind == "rwkv":
         H = d // head_dim(cfg)
         return 2 * d * 2 + 7 * d * 2 + d * 4 + H * head_dim(cfg) * 4 + d * 4
-    raise KeyError(kind)
+    raise Uncounted(kind)
 
 
 def mixer_flops(cfg: dict, kind: str, attended: int) -> int:
@@ -64,7 +78,7 @@ def mixer_flops(cfg: dict, kind: str, attended: int) -> int:
     if kind == "rwkv":
         hd = head_dim(cfg)
         return 4 * cfg["d_model"] * hd
-    raise KeyError(kind)
+    raise Uncounted(kind)
 
 
 def matmul_weights(cfg: dict, head: bool = True) -> int:
@@ -113,7 +127,7 @@ def state_bytes(cfg: dict, kind: str, ctx: int) -> int:
         wkv = cfg["d_model"] * hd * 4                          # f32 state
         shifts = 2 * cfg["d_model"] * 2
         return 2 * (wkv + shifts)                              # read, write
-    raise KeyError(kind)
+    raise Uncounted(kind)
 
 
 def decode_bytes(cfg: dict, ctx: list[int]) -> int:

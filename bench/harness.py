@@ -4,9 +4,16 @@ served tokens against the plain reference, print the result line.
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by its name (``Layout``): a cell names its
 configuration and traffic in ``BENCHMARK.json``; the configuration file
-names its reference; the traffic file names its generator; a per-layer
-metric ``a.b`` is read by ``metrics/a.py``.  So a later cell, mix or
-metric is added by adding files and entries, not by editing these.
+names its reference and, where ``flops.py`` cannot count its blocks, its
+count of operations and bytes (``counts/<count>.py``); the traffic file
+names its generator; a per-layer metric ``a.b`` is read by
+``metrics/a.py``.  So a later cell, mix or metric is added by adding
+files and entries, not by editing these.
+
+A traced run (``--trace 1``) attaches the program's span log
+(``repro.core.trace.SpanLog``) from the end of the warm-up to the end of
+the window, and hands its records to the readers; an untraced run
+attaches nothing.
 
 The window is open loop on the host clock: before each engine step every
 request whose due time has passed is submitted, and after the step every
@@ -57,6 +64,16 @@ class Layout:
 
     def reference(self, name: str):
         return _module(self.bench / "reference" / f"{name}.py")
+
+    def count(self, name: str = None):
+        """A configuration's count of operations and bytes: ``decode_flops``
+        and ``decode_bytes`` as ``flops.py`` has them, from
+        ``counts/<name>.py``, or ``flops.py`` itself for none (imported
+        as ``bench.flops``, whose ``Uncounted`` the readers catch)."""
+        if not name:
+            from bench import flops
+            return flops
+        return _module(self.bench / "counts" / f"{name}.py")
 
     def metric(self, name: str):
         return _module(self.bench / "metrics" / f"{name.split('.')[0]}.py")
@@ -490,11 +507,12 @@ def sample_finished(recs, seed: int, want_tokens: int, most: int):
 
 def main(argv=None, *, root: Path = None, bench: Path = None,
          require_tpu: bool = True, cache: bool = True, fault=None,
-         t_process: float = None) -> int:
+         t_process: float = None, out: dict = None) -> int:
     """One run.  ``require_tpu=False`` and ``cache`` (a directory for
     the compile cache in place of the checkout's) let a test drive it on
     the CPU; ``fault(engine)`` breaks the served path underneath for a
-    test of the check."""
+    test of the check; ``out``, a dict, receives what the readers read
+    (``ctx``), for a caller that reads more from the run."""
     t_process = time.perf_counter() if t_process is None else t_process
     clock = time.perf_counter
     args = parse(argv)
@@ -533,7 +551,7 @@ def main(argv=None, *, root: Path = None, bench: Path = None,
         cache_dir = str(layout.root / ".jax_cache" if cache is True
                         else cache)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # the prefill path runs op by op: cache its small programs too
+        # the page-in runs op by op: cache its small programs too
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # no eviction: a cache that evicts scans every entry on each write
@@ -548,6 +566,7 @@ def main(argv=None, *, root: Path = None, bench: Path = None,
     pconf["block_pattern"] = tuple(pconf.get("block_pattern", ("attn",)))
     cfg = ModelConfig(**pconf)
     ref_mod = layout.reference(conf["reference"])
+    count = layout.count(conf.get("count"))
     gen = layout.generator(traffic["process"])
 
     t = clock()
@@ -572,6 +591,11 @@ def main(argv=None, *, root: Path = None, bench: Path = None,
 
     if fault is not None:
         fault(eng)
+    program_log = None
+    if args.trace:
+        from repro.core import trace as program_trace
+        program_log = program_trace.SpanLog(clock)
+        program_trace.attach(program_log)
     trace_dir = None
     trace_s = min(args.seconds, float(traffic.get("trace_s", args.seconds)))
     if args.trace:
@@ -596,19 +620,23 @@ def main(argv=None, *, root: Path = None, bench: Path = None,
             else:
                 pauses.append((_t[0], clock() - _t[0], info["generation"]))
         gc.callbacks.append(gc_timer)
-    drv.run_until(t0)
-    c0, h0 = compiles.compiled(), compiles.hits
-    if args.trace:
-        # the traced part is the window's last trace_s seconds: stopping
-        # the profiler holds the host for seconds, which must fall after
-        # the window; it starts a second early, so that its own start
-        # stays outside the part it reads
-        drv.run_until(t1 - trace_s - 1.0)
-        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
-        drv.run_until(t1 - trace_s)
-        with jax.profiler.TraceAnnotation("bench.window"):
-            drv.run_until(t1)
-    drv.run_until(t1)
+    try:
+        drv.run_until(t0)
+        c0, h0 = compiles.compiled(), compiles.hits
+        if args.trace:
+            # the traced part is the window's last trace_s seconds:
+            # stopping the profiler holds the host for seconds, which must
+            # fall after the window; it starts a second early, so that its
+            # own start stays outside the part it reads
+            drv.run_until(t1 - trace_s - 1.0)
+            jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+            drv.run_until(t1 - trace_s)
+            with jax.profiler.TraceAnnotation("bench.window"):
+                drv.run_until(t1)
+        drv.run_until(t1)
+    finally:
+        if program_log is not None:
+            program_trace.detach()
     setup_s = t0 - t_process
     window_compiles = compiles.compiled() - c0
     window_loads = compiles.hits - h0
@@ -653,9 +681,12 @@ def main(argv=None, *, root: Path = None, bench: Path = None,
     # readers of host spans and records see the whole window [t0, t1);
     # readers of the device trace see its traced part [trace_t0, t1)
     ctx = {"cfg": cfg, "conf": conf, "traffic": traffic, "peaks": peaks,
-           "recs": recs, "t0": t0, "t1": t1, "trace_t0": t1 - trace_s,
-           "spans": span_items, "counters": counters,
-           "step_starts": step_starts, "trace_s": trace_s}
+           "count": count, "recs": recs, "t0": t0, "t1": t1,
+           "trace_t0": t1 - trace_s, "spans": span_items,
+           "program_spans": (program_log.records if program_log is not None
+                             else []),
+           "counters": counters, "step_starts": step_starts,
+           "trace_s": trace_s}
     del eng, drv, params, sample
     gc.collect()
 
@@ -717,6 +748,8 @@ def main(argv=None, *, root: Path = None, bench: Path = None,
     for name, c in checks.items():
         log(f"check {name}: {c['value']} (limit {c['limit']})")
     print(json.dumps(result), flush=True)
+    if out is not None:
+        out.update(ctx)
     return 0
 
 

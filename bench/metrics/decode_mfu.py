@@ -1,15 +1,15 @@
 """Decode step's model FLOP/s utilization: the operations of the live
-sequences' tokens (``bench/flops.py``) over the device time of the traced
-decode calls times the chip's peak."""
+sequences' tokens (by the configuration's count, ``bench/flops.py``
+unless it names one) over the device time of the traced decode calls
+times the chip's peak."""
 
-from bench import flops
-from bench.metrics_common import traced_decodes
+from bench.metrics_common import decode_work
 
 
 def read(name, ctx):
-    pairs = traced_decodes(ctx)
-    if not pairs:
+    work = decode_work(name, ctx)
+    if not work:
         return None
-    cfg, peaks = ctx["conf"]["program"], ctx["peaks"]
-    ops = sum(flops.decode_flops(cfg, c) for c, _ in pairs)
-    return 100.0 * ops / (sum(t for _, t in pairs) * peaks["bf16_flops"])
+    ops = sum(f for f, _, _ in work)
+    return 100.0 * ops / (sum(t for _, _, t in work)
+                          * ctx["peaks"]["bf16_flops"])

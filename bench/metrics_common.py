@@ -1,6 +1,8 @@
 """What several per-layer readers share."""
 
-from bench import trace
+import sys
+
+from bench import flops, trace
 
 
 def traced_decodes(ctx) -> list[tuple]:
@@ -14,3 +16,20 @@ def traced_decodes(ctx) -> list[tuple]:
     host = [info["ctx"] for n, a, b, info in ctx["spans"]
             if n == "decode" and t0 <= a and b <= t1]
     return [(c, t) for c, t in zip(host, dev) if t > 0 and c]
+
+
+def decode_work(name, ctx) -> list[tuple]:
+    """``(operations, bytes, device seconds)`` of each traced decode
+    call, by the configuration's count (``ctx["count"]``: its
+    ``counts/<count>.py``, or ``bench/flops.py``).  Empty when nothing was
+    traced; None, with one line on stderr, when the count raises
+    ``flops.Uncounted`` (a block kind it has no count for)."""
+    pairs = traced_decodes(ctx)
+    count, cfg = ctx["count"], ctx["conf"]["program"]
+    try:
+        return [(count.decode_flops(cfg, c), count.decode_bytes(cfg, c), t)
+                for c, t in pairs]
+    except flops.Uncounted as e:
+        print(f"{name}: {count.__file__} cannot count "
+              f"{cfg.get('name')!r}: {e}", file=sys.stderr, flush=True)
+        return None

@@ -4,33 +4,31 @@ The program records its phases in a ``repro.core.trace.SpanLog``:
 ``engine.step`` and the ``engine.*`` phases inside it (schedule, prefill,
 splice, decode, retire, extract), and the paged backend's own steps
 (``prefill.*``, ``decode.*``, ``splice.page_in``).  A record is
-``(name, t0, t1, parent, info)`` on the host clock; with a profile being
-captured each span is also a ``repro.<name>`` host event on the trace's
-clock.  The jitted decode is named ``paged_decode``, so each of its
-executions is a ``jit_paged_decode`` program on the TPU's module line.
+``(name, t0, t1, parent, info)`` on the host clock.  The harness attaches
+a log after the warm-up of a traced run (``--trace 1``) and detaches it
+after the window; its records are ``ctx["program_spans"]``.  With the
+profile being captured each span is also a ``repro.<name>`` host event on
+the trace's clock, which ``bench.trace.load`` keeps as
+``program_marks``.  The jitted decode is named ``paged_decode``, so each
+of its executions is a ``jit_paged_decode`` program on the TPU's module
+line (``modules``).
 
-This module reads both:
+This module holds the readers of both: of the log's records (host clock,
+the whole window) and of the profile (its traced part), which the
+per-layer metrics in ``bench/metrics/`` call.  Run as
 
-* ``load``: the ``repro.*`` host events and the TPU's program executions
-  of a profile (``bench.trace.load`` keeps the device operations and the
-  benchmark's own ``bench.*`` spans);
-* readers of the log's records (host clock, the whole window) and of the
-  profile (its traced part);
-* ``python3 bench/program_spans.py <the arguments of bench/run.py>``: one
-  run of a cell as ``bench/run.py`` makes it, with a span log attached
-  after the warm-up (its profiler annotations land in the profile under
-  ``--trace 1``, and record nothing otherwise).  After the run's own
-  result line it prints one more, ``{"program_spans": {...}}``: the
-  readings below, and the spans of every window step that took over a
-  second (the stall finder).  The log rides on ``harness.main``'s
-  after-warm-up hook.
+    python3 bench/program_spans.py <the arguments of bench/run.py>
+
+it makes one run as ``bench/run.py`` does and, after the run's own result
+line, prints one more, ``{"program_spans": {...}}``: the readings below,
+the self time of every phase, and the spans of every window step that
+took over a second (the stall finder).  Only a traced run holds a log.
 """
 
 from __future__ import annotations
 
 import bisect
 import json
-import shutil
 import sys
 from pathlib import Path
 
@@ -39,56 +37,13 @@ if __name__ == "__main__":
 
 from bench import trace  # noqa: E402
 
-MODULES_LINE = "XLA Modules"
 DECODE_PROGRAM = "jit_paged_decode"
 SLOW_STEP_S = 1.0
 
 
 # ---------------------------------------------------------------------------
-# the profile
+# the profile (``bench.trace.load``'s dict)
 # ---------------------------------------------------------------------------
-
-def load(trace_dir) -> dict:
-    """The ``repro.*`` host events (``program_marks``: name without the
-    prefix, start, end in ns), each TPU program execution (``modules``:
-    name without its ``(id)``, start, end, chip) and the benchmark's
-    window span, from the newest profile under ``trace_dir``."""
-    from jax.profiler import ProfileData
-    paths = sorted(Path(trace_dir).rglob("*.xplane.pb"),
-                   key=lambda p: p.stat().st_mtime)
-    if not paths:
-        return {"program_marks": [], "modules": [], "window": None}
-    pd = ProfileData.from_file(str(paths[-1]))
-    marks, modules, window = [], [], None
-    for plane in pd.planes:
-        m = trace.DEVICE.match(plane.name)
-        if m:
-            for line in plane.lines:
-                if line.name == MODULES_LINE:
-                    modules.extend((e.name.split("(")[0], e.start_ns,
-                                    e.start_ns + e.duration_ns,
-                                    int(m.group(1))) for e in line.events)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith("repro."):
-                        marks.append((e.name[6:], e.start_ns,
-                                      e.start_ns + e.duration_ns))
-                    elif e.name == "bench.window":
-                        window = (e.start_ns, e.start_ns + e.duration_ns)
-    marks.sort(key=lambda x: (x[1], -x[2]))
-    modules.sort(key=lambda x: x[1])
-    return {"program_marks": marks, "modules": modules, "window": window}
-
-
-def run_profile(ctx, bench_dir: Path):
-    """``load`` of the profile this run wrote, found beside the
-    checkout's benchmark files (``<checkout>/.bench_trace/``), or None
-    when its window is not the one ``ctx["trace"]`` holds."""
-    pf = load(Path(bench_dir).resolve().parent / ".bench_trace")
-    w = trace.window(ctx["trace"])
-    return pf if w is not None and pf["window"] == w else None
-
 
 class Busy:
     """The union of device-busy intervals of a profile, for many
@@ -140,7 +95,7 @@ def innermost(marks, lo, hi) -> list[tuple]:
     return out
 
 
-def idle_by_phase(td: dict, pf: dict) -> dict:
+def idle_by_phase(td: dict) -> dict:
     """Device-idle seconds of the traced part, by the innermost program
     span the host was in (``outside`` for none: the harness's
     loop between steps)."""
@@ -148,7 +103,7 @@ def idle_by_phase(td: dict, pf: dict) -> dict:
     if w is None or not td["ops"]:
         return {}
     idle = Busy(td).idle(*w)
-    pieces = innermost(pf["program_marks"], *w)
+    pieces = innermost(td["program_marks"], *w)
     out: dict = {}
     i = 0
     for a, b in idle:
@@ -173,18 +128,18 @@ def idle_below_step_share(by_phase: dict) -> float:
     return below / total if total else None
 
 
-def _whole(pf: dict, td: dict, name: str) -> list[tuple]:
+def _whole(td: dict, name: str) -> list[tuple]:
     w = trace.window(td)
     if w is None:
         return []
-    return [m for m in pf["program_marks"]
+    return [m for m in td["program_marks"]
             if m[0] == name and w[0] <= m[1] and m[2] <= w[1]]
 
 
-def prefill_idle_ms_per_wave(td: dict, pf: dict):
+def prefill_idle_ms_per_wave(td: dict):
     """Device-idle ms inside each ``engine.prefill`` lying wholly in the
     traced part, mean per wave; None when the part holds none."""
-    waves = _whole(pf, td, "engine.prefill")
+    waves = _whole(td, "engine.prefill")
     if not waves or not td["ops"]:
         return None
     busy = Busy(td)
@@ -192,13 +147,13 @@ def prefill_idle_ms_per_wave(td: dict, pf: dict):
                for _, a, b in waves) * 1e3 / len(waves)
 
 
-def decode_idle_ms_per_step(td: dict, pf: dict):
+def decode_idle_ms_per_step(td: dict):
     """Device-idle ms inside each ``engine.step`` of the traced part that
     holds a decode and neither a prefill nor a splice, per such step."""
-    steps = _whole(pf, td, "engine.step")
+    steps = _whole(td, "engine.step")
     if not steps or not td["ops"]:
         return None
-    marks = pf["program_marks"]
+    marks = td["program_marks"]
     starts = [m[1] for m in marks]
     busy = Busy(td)
     idle = []
@@ -212,14 +167,14 @@ def decode_idle_ms_per_step(td: dict, pf: dict):
     return sum(idle) * 1e3 / len(idle) if idle else None
 
 
-def program_ms(td: dict, pf: dict, module: str = DECODE_PROGRAM):
+def program_ms(td: dict, module: str = DECODE_PROGRAM):
     """Device time of each execution of the program ``module`` lying
     wholly in the traced part, found by its name, mean per call in ms."""
     w = trace.window(td)
     if w is None or not td["ops"]:
         return None
     busy = Busy(td)
-    calls = [busy.inside(a, b) for n, a, b, _ in pf["modules"]
+    calls = [busy.inside(a, b) for n, a, b, _ in td["modules"]
              if n == module and w[0] <= a and b <= w[1]]
     calls = [c for c in calls if c > 0]
     return sum(calls) * 1e3 / len(calls) if calls else None
@@ -313,7 +268,7 @@ def slow_steps(records, t0: float, t1: float, over_s: float = SLOW_STEP_S
     return out
 
 
-def summary(records, t0: float, t1: float, td=None, pf=None) -> dict:
+def summary(records, t0: float, t1: float, td=None) -> dict:
     """What one run's line prints: host readings over the window, and,
     given the profile, device readings over its traced part."""
     out = {"sched_ms_per_step": sched_ms_per_step(records, t0, t1),
@@ -322,67 +277,27 @@ def summary(records, t0: float, t1: float, td=None, pf=None) -> dict:
            "steps": len(window_steps(records, t0, t1)),
            "by_phase": by_phase(records, t0, t1),
            "slow_steps": slow_steps(records, t0, t1)}
-    if td is not None and pf is not None:
-        idle = idle_by_phase(td, pf)
+    if td is not None:
+        idle = idle_by_phase(td)
         out.update({
-            "prefill_idle_ms_per_wave": prefill_idle_ms_per_wave(td, pf),
-            "decode_idle_ms_per_step": decode_idle_ms_per_step(td, pf),
-            "decode_program_ms": program_ms(td, pf),
+            "prefill_idle_ms_per_wave": prefill_idle_ms_per_wave(td),
+            "decode_idle_ms_per_step": decode_idle_ms_per_step(td),
+            "decode_program_ms": program_ms(td),
             "idle_s_by_phase": idle,
             "idle_below_step_share": idle_below_step_share(idle)})
     return out
 
 
-# ---------------------------------------------------------------------------
-# one run with the log attached
-# ---------------------------------------------------------------------------
-
-def run_attached(argv, *, root: Path = None, **kw) -> dict:
-    """``bench/run.py``'s run with the program's span log attached after
-    the warm-up; ``kw`` goes to ``harness.main`` (a test's CPU run).
-    Returns its exit code, the log's records, the window on the host
-    clock and, under ``--trace 1``, the profile's directory.
-
-    This leans on where ``harness.main`` calls its ``fault`` hook: the
-    window is taken as the hook's clock reading plus the ramp.  Once the
-    harness attaches the log itself, this goes, and the window is the
-    harness's own."""
-    from bench import harness
-    args = harness.parse(argv)
-    layout = harness.Layout(root or harness.BENCH_DIR.parent)
-    cell = harness.cell_of(layout.benchmark(), args.workload)
-    ramp = float(layout.traffic(cell["traffic"]).get("ramp_s", 0.0))
-    trace_dir = layout.root / ".bench_trace" / args.workload
-    # cleared here, so that the window starts right after the hook
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    got: dict = {}
-
-    def attach(eng):
-        from repro.core import trace as program_trace
-        got["log"] = program_trace.SpanLog()
-        program_trace.attach(got["log"])
-        got["start"] = got["log"].clock()
-
-    try:
-        rc = harness.main(argv, root=root, fault=attach, **kw)
-    finally:
-        if "log" in got:
-            from repro.core import trace as program_trace
-            program_trace.detach()
-    t0 = got["start"] + ramp
-    return {"rc": rc, "records": got["log"].records, "t0": t0,
-            "t1": t0 + args.seconds,
-            "trace_dir": trace_dir if args.trace else None}
-
-
 def main(argv=None, **kw) -> int:
-    run = run_attached(argv, **kw)
-    td = pf = None
-    if run["trace_dir"] is not None:
-        td, pf = trace.load(run["trace_dir"]), load(run["trace_dir"])
+    """``bench/run.py``'s run, then the line of the harness's own span
+    log; ``kw`` goes to ``harness.main`` (a test's CPU run)."""
+    from bench import harness
+    ctx: dict = {}
+    rc = harness.main(argv, out=ctx, **kw)
     print(json.dumps({"program_spans": summary(
-        run["records"], run["t0"], run["t1"], td, pf)}), flush=True)
-    return run["rc"]
+        ctx["program_spans"], ctx["t0"], ctx["t1"], ctx.get("trace"))}),
+        flush=True)
+    return rc
 
 
 if __name__ == "__main__":

@@ -74,3 +74,16 @@ def compile_cache(tmp_path):
     for n, v in saved.items():
         jax.config.update(n, v)
     cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def task_ids_from_zero():
+    """A run of the engine draws task ids from the program's process-wide
+    counter, which seeds the scheduler's jitter.  The program's tests that
+    depend on it start it from zero with ``repro.core.reset_ids()``; so
+    does each benchmark test when it ends, so that a later test of another
+    file in the same process (``tests/test_simulator.py``'s Fibonacci
+    gains, which do not reset it) is not shifted by these runs."""
+    yield
+    from repro.core import reset_ids
+    reset_ids()

@@ -42,7 +42,25 @@ def test_cell_files_exist(cell):
         (REPO / "bench/traffic" / f"{cell['traffic']}.json").read_text())
     assert (REPO / "bench/generators"
             / f"{traffic['process']}.py").is_file()
-    assert cell["chips"] == 1 and len(cell["why"]) <= 200
+    if "count" in cfile:
+        assert (REPO / "bench/counts" / f"{cfile['count']}.py").is_file()
+    assert cell["chips"] in (1, 4) and len(cell["why"]) <= 200
+
+
+def four_chip_cells_allowed(cells: list) -> bool:
+    """At most half the cells, rounded down, ask for four chips; one
+    always may."""
+    four = sum(1 for c in cells if c["chips"] == 4)
+    return four <= max(1, len(cells) // 2)
+
+
+def test_four_chip_cells_are_at_most_half():
+    assert four_chip_cells_allowed(SPEC["workloads"])
+    one, four = {"chips": 1}, {"chips": 4}
+    assert four_chip_cells_allowed([four])
+    assert four_chip_cells_allowed([four, one])
+    assert not four_chip_cells_allowed([four, four, one])
+    assert four_chip_cells_allowed([four, four, one, one])
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -71,3 +89,33 @@ def test_bounds():
     for m in SPEC["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25
         assert m["source"] in ("host_clock", "device_trace")
+
+
+def test_a_whole_prefill_wave_lies_in_every_traced_part():
+    """``prefill_idle_ms_per_wave`` reads the waves that lie wholly in the
+    traced part, the window's last ``trace_s`` seconds: every seed's
+    order of the window's gaps has to put an arrival there at least
+    ``TAIL`` seconds before the window ends (a wave waits out a decode
+    step, then prefills a prompt of up to 1536 tokens in some 150 ms).
+    Arrivals sit mid-gap, so counting back from the window's end the
+    first arrival at least ``TAIL`` back lies within ``2 * TAIL`` plus
+    half the longest gap."""
+    from bench.generators import open_loop
+    TAIL = 0.3
+    m = next(m for m in SPEC["per_layer"]
+             if m["name"] == "prefill_idle_ms_per_wave")
+    for name in m["workloads"]:
+        cell = next(c for c in SPEC["workloads"] if c["name"] == name)
+        traffic = json.loads((REPO / "bench/traffic"
+                              / f"{cell['traffic']}.json").read_text())
+        ramp, run = float(traffic["ramp_s"]), SPEC["run_seconds"]
+        trace_s = min(run, float(traffic.get("trace_s", run)))
+        dues = [r.due - ramp for r in open_loop.generate(
+            traffic, 1, 256, ramp + run) if r.due >= ramp]
+        gaps, back = [], 0.0
+        for d in dues:                       # d = back + g / 2
+            gaps.append(2.0 * (d - back))
+            back = d + gaps[-1] / 2.0
+        assert back == pytest.approx(run)
+        worst = 2.0 * TAIL + max(gaps) / 2.0
+        assert worst <= trace_s, (name, worst, trace_s)

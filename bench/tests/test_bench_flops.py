@@ -76,5 +76,12 @@ def test_full_models_and_roofline():
 
 
 def test_unknown_block_kind_is_an_error():
-    with pytest.raises(KeyError):
+    with pytest.raises(flops.Uncounted):
         flops.layer_matmul_weights({"d_model": 8, "d_ff": 8}, "moe")
+    for kind in ("moe", "rec"):
+        with pytest.raises(flops.Uncounted):
+            flops.decode_bytes(dict(program("yi-6b"), block_pattern=[kind]),
+                               [10])
+    # an expert FFN would be counted as one dense FFN of d_ff: refused
+    with pytest.raises(flops.Uncounted, match="expert"):
+        flops.decode_flops(dict(program("yi-6b"), n_experts=8), [10])

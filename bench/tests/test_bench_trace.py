@@ -84,3 +84,59 @@ def test_recorded_chip_trace(path):
     bd = trace.breakdown(td)
     assert len(bd["device_ops"]) <= 10 and len(bd["idle_gaps"]) <= 10
     assert sum(s for _, s in bd["idle_gaps"]) <= b["window_s"] - b["busy_s"] + 1e-9
+
+
+# Each reader on the recorded slices, as the benchmark read them before
+# configurations could name a count of their own and before the harness
+# attached the program's span log: the same numbers, digit for digit.
+# The decode calls' live lengths are made up, the same for every reader.
+BEFORE = [
+    ("trace_yi6b_chat.json", "yi-6b", "decode_roofline", 54.08878236712393),
+    ("trace_yi6b_chat.json", "yi-6b", "decode_mfu", 1.7988412335457997),
+    ("trace_yi6b_chat.json", "yi-6b", "decode_step_ms", 26.609443249999998),
+    ("trace_yi6b_chat.json", "yi-6b", "idle_share", 10.245885),
+    ("trace_yi6b_chat.json", "rwkv6-3b", "decode_roofline",
+     28.230918572151097),
+    ("trace_yi6b_chat.json", "rwkv6-3b", "decode_mfu", 0.889739103150489),
+    ("program_spans_yi6b_chat.json", "yi-6b", "decode_roofline",
+     53.383206853576986),
+    ("program_spans_yi6b_chat.json", "yi-6b", "decode_mfu",
+     1.7753757724417063),
+    ("program_spans_yi6b_chat.json", "yi-6b", "decode_step_ms",
+     26.961744666666664),
+    ("program_spans_yi6b_chat.json", "yi-6b", "idle_share",
+     66.18894559987282),
+    ("program_spans_yi6b_chat.json", "rwkv6-3b", "decode_roofline",
+     27.862033222566644),
+    ("program_spans_yi6b_chat.json", "rwkv6-3b", "decode_mfu",
+     0.8781131364195163),
+    ("program_spans_yi6b_chat.json", "yi-6b", "decode_program_ms",
+     25.865606666666665),
+    ("program_spans_yi6b_chat.json", "yi-6b", "prefill_idle_ms_per_wave",
+     216.13313200000002),
+    ("program_spans_yi6b_chat.json", "yi-6b", "decode_idle_ms_per_step",
+     3.217182499999999),
+]
+
+
+@pytest.mark.parametrize("fname,config,metric,want", BEFORE,
+                         ids=[f"{f.split('_')[0]}-{c}-{m}"
+                              for f, c, m, _ in BEFORE])
+def test_readers_read_as_before_on_recorded_data(fname, config, metric,
+                                                 want):
+    from bench import harness
+    layout = harness.Layout(DATA.parents[2])
+    d = json.loads((DATA / fname).read_text())
+    td = {"program_marks": [], "modules": []}
+    td.update({k: [tuple(x) for x in v] if isinstance(v, list) else v
+               for k, v in d.items()})
+    w = trace.window(td)
+    host = [("decode", a / 1e9, b / 1e9,
+             {"ctx": [300 + 17 * i + k for i in range(8)]})
+            for k, (_, a, b) in enumerate(m for m in td["marks"]
+                                          if m[0] == "decode")]
+    conf = layout.config(config)
+    ctx = {"trace": td, "conf": conf, "count": layout.count(conf.get("count")),
+           "peaks": layout.peaks()["devices"]["TPU v5 lite"], "spans": host,
+           "trace_t0": w[0] / 1e9, "t1": w[1] / 1e9}
+    assert layout.metric(metric).read(metric, ctx) == want

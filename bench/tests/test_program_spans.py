@@ -1,6 +1,7 @@
 """The program's spans against the device trace: the readers on a
 hand-made profile and log, on a slice of a profile recorded on the chip,
-and one run of the tiny CPU cell with the log attached."""
+and traced runs of the tiny CPU cell, in which the harness attaches the
+log."""
 
 import json
 from pathlib import Path
@@ -18,11 +19,6 @@ def made():
     outside every step 60-62, then a step 62-98 that prefills, splices
     and decodes.  The device runs the decode 20-48, a prefill op 70-78
     and the next decode 96-100."""
-    td = {"ops": [(0, 5, "before", 0), (20, 48, "fusion.1", 0),
-                  (70, 78, "while.2", 0), (96, 100, "fusion.1", 0)],
-          "marks": [("window", 10, 100), ("step", 10, 60),
-                    ("decode", 14, 50), ("step", 62, 98)],
-          "devices": 1}
     marks = [("engine.step", 10, 60), ("engine.schedule", 10, 14),
              ("engine.decode", 14, 50), ("decode.prep", 14, 18),
              ("decode.launch", 18, 20), ("decode.readback", 20, 50),
@@ -31,16 +27,19 @@ def made():
              ("engine.prefill", 63, 90), ("prefill.forward", 63, 80),
              ("prefill.readback", 80, 85), ("prefill.handles", 85, 90),
              ("engine.splice", 90, 95), ("engine.decode", 95, 98)]
-    pf = {"program_marks": marks, "window": (10, 100),
-          "modules": [("jit_paged_decode", 20, 48, 0),
-                      ("jit_paged_decode", 96, 100, 0),
-                      ("jit_argmax", 48, 49, 0)]}
-    return td, pf
+    return {"ops": [(0, 5, "before", 0), (20, 48, "fusion.1", 0),
+                    (70, 78, "while.2", 0), (96, 100, "fusion.1", 0)],
+            "marks": [("window", 10, 100), ("step", 10, 60),
+                      ("decode", 14, 50), ("step", 62, 98)],
+            "devices": 1, "program_marks": marks,
+            "modules": [("jit_paged_decode", 20, 48, 0),
+                        ("jit_paged_decode", 96, 100, 0),
+                        ("jit_argmax", 48, 49, 0)]}
 
 
 def test_idle_time_is_put_down_to_the_innermost_program_span():
-    td, pf = made()
-    got = ps.idle_by_phase(td, pf)
+    td = made()
+    got = ps.idle_by_phase(td)
     want = {"engine.schedule": 5, "decode.prep": 4, "decode.launch": 2,
             "decode.readback": 2, "engine.retire": 8, "engine.step": 2,
             "outside": 2, "prefill.forward": 9, "prefill.readback": 5,
@@ -53,58 +52,75 @@ def test_idle_time_is_put_down_to_the_innermost_program_span():
 
 
 def test_idle_inside_prefill_waves_and_decode_only_steps():
-    td, pf = made()
+    td = made()
     # the wave 63-90 holds 8 busy of 27
-    assert ps.prefill_idle_ms_per_wave(td, pf) == pytest.approx(19e-6)
+    assert ps.prefill_idle_ms_per_wave(td) == pytest.approx(19e-6)
     # only the first step decodes without prefill or splice: 22 of 50 idle
-    assert ps.decode_idle_ms_per_step(td, pf) == pytest.approx(22e-6)
+    assert ps.decode_idle_ms_per_step(td) == pytest.approx(22e-6)
 
 
 def test_program_time_is_found_by_name():
-    td, pf = made()
-    assert ps.program_ms(td, pf) == pytest.approx(16e-6)
+    td = made()
+    assert ps.program_ms(td) == pytest.approx(16e-6)
     # a program whose decode bears another name reads nothing
-    pf["modules"] = [("jit_step",) + m[1:] for m in pf["modules"]]
-    assert ps.program_ms(td, pf) is None
+    td["modules"] = [("jit_step",) + m[1:] for m in td["modules"]]
+    assert ps.program_ms(td) is None
 
 
 def test_no_traced_part_no_device_readings():
-    td, pf = made()
+    td = made()
     td["marks"] = [m for m in td["marks"] if m[0] != "window"]
-    assert ps.idle_by_phase(td, pf) == {}
-    assert ps.prefill_idle_ms_per_wave(td, pf) is None
-    assert ps.decode_idle_ms_per_step(td, pf) is None
-    assert ps.program_ms(td, pf) is None
+    assert ps.idle_by_phase(td) == {}
+    assert ps.prefill_idle_ms_per_wave(td) is None
+    assert ps.decode_idle_ms_per_step(td) is None
+    assert ps.program_ms(td) is None
 
 
-def test_the_reader_finds_no_profile_of_this_run(tmp_path):
-    """``decode_program_ms`` reads the run's own profile, and nothing
-    when the profile on disk is not the one the harness loaded."""
+def reader(name):
     from bench import harness
-    reader = harness.Layout(Path(__file__).resolve().parents[2]).metric(
-        "decode_program_ms")
-    td, _ = made()
-    (tmp_path / "bench").mkdir()
-    assert ps.run_profile({"trace": td}, tmp_path / "bench") is None
-    assert reader.read("decode_program_ms", {"trace": td}) is None
+    return harness.Layout(Path(__file__).resolve().parents[2]).metric(name)
 
 
-def test_the_reader_says_when_decode_calls_have_no_program(
-        tmp_path, monkeypatch, capsys):
+def test_the_reader_finds_no_profile_of_this_run():
+    """``decode_program_ms`` reads the programs of the profile the harness
+    loaded, and nothing from one that holds none (a profile with no TPU:
+    no modules, no decode calls), silently."""
+    td = made()
+    td.update(ops=[], modules=[])
+    assert reader("decode_program_ms").read("decode_program_ms",
+                                            {"trace": td}) is None
+
+
+def test_the_reader_says_when_decode_calls_have_no_program(capsys):
     """Decode calls in the traced part but no ``jit_paged_decode``
     program: the reader reads nothing and says so on stderr; with the
     program there it reads it and says nothing."""
-    from bench import harness
-    reader = harness.Layout(Path(__file__).resolve().parents[2]).metric(
-        "decode_program_ms")
-    td, pf = made()
-    monkeypatch.setattr(ps, "run_profile", lambda ctx, bench_dir: pf)
-    assert reader.read("decode_program_ms", {"trace": td}) == \
-        pytest.approx(16e-6)
+    td = made()
+    read = reader("decode_program_ms").read
+    assert read("decode_program_ms", {"trace": td}) == pytest.approx(16e-6)
     assert capsys.readouterr().err == ""
-    pf["modules"] = [("jit_step",) + m[1:] for m in pf["modules"]]
-    assert reader.read("decode_program_ms", {"trace": td}) is None
+    td["modules"] = [("jit_step",) + m[1:] for m in td["modules"]]
+    assert read("decode_program_ms", {"trace": td}) is None
     assert "no jit_paged_decode program" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("name,want", [
+    ("prefill_idle_ms_per_wave", 19e-6), ("decode_idle_ms_per_step", 22e-6),
+    ("decode_program_ms", 16e-6)])
+def test_readers_of_the_profile_read_the_harness_trace(name, want):
+    """The per-layer metrics of ``BENCHMARK.json`` that read the
+    program's spans in the profile take them from ``ctx["trace"]``."""
+    assert reader(name).read(name, {"trace": made()}) == \
+        pytest.approx(want)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("sched_ms_per_step", 85.0), ("prefill_cache_loads_per_wave", 3.0)])
+def test_readers_of_the_log_read_the_harness_records(name, want):
+    ctx = {"program_spans": records(), "t0": 1.0, "t1": 3.0}
+    assert reader(name).read(name, ctx) == pytest.approx(want)
+    # an untraced run's ctx holds no records: nothing to read
+    assert reader(name).read(name, dict(ctx, program_spans=[])) is None
 
 
 def records():
@@ -165,20 +181,17 @@ def test_recorded_chip_profile(path):
     """A profile recorded on one TPU v5e with the span log attached,
     trimmed to one prefill wave and the decode steps after it."""
     d = json.loads(path.read_text())
-    td = {"ops": [tuple(o) for o in d["ops"]],
-          "marks": [tuple(m) for m in d["marks"]], "devices": d["devices"]}
-    pf = {"program_marks": [tuple(m) for m in d["program_marks"]],
-          "modules": [tuple(m) for m in d["modules"]],
-          "window": trace.window(td)}
+    td = {k: [tuple(x) for x in v] if isinstance(v, list) else v
+          for k, v in d.items()}
     b = trace.busy(td)
-    idle = ps.idle_by_phase(td, pf)
+    idle = ps.idle_by_phase(td)
     assert sum(idle.values()) == pytest.approx(b["window_s"] - b["busy_s"])
     assert ps.idle_below_step_share(idle) >= 0.9
-    assert ps.prefill_idle_ms_per_wave(td, pf) > 0
-    assert ps.decode_idle_ms_per_step(td, pf) > 0
+    assert ps.prefill_idle_ms_per_wave(td) > 0
+    assert ps.decode_idle_ms_per_step(td) > 0
     # the decode found by its name takes the device time the benchmark's
     # span around the backend call holds, less the argmax and uploads
-    by_name = ps.program_ms(td, pf)
+    by_name = ps.program_ms(td)
     by_span = [c * 1e3 for c in trace.per_call(td, "decode") if c > 0]
     assert by_name and by_span
     assert by_name == pytest.approx(sum(by_span) / len(by_span), rel=0.05)
@@ -186,8 +199,9 @@ def test_recorded_chip_profile(path):
 
 
 def test_tiny_cell_with_the_log_attached(checkout, compile_cache, capsys):
-    """The run prints its own result line, then the program's spans:
-    the scheduler's host time per step and the prefill's cache loads."""
+    """The run prints its own result line, with the four readers of the
+    span log the harness attached, then the program's spans: the
+    scheduler's host time per step and the prefill's cache loads."""
     args = ["--workload", "tiny.chat", "--seed", str(2**31 + 5),
             "--seconds", "6", "--trace", "1"]
     rc = ps.main(args, root=checkout, require_tpu=False, cache=compile_cache)
@@ -195,6 +209,13 @@ def test_tiny_cell_with_the_log_attached(checkout, compile_cache, capsys):
     out = capsys.readouterr().out.strip().splitlines()
     res, got = json.loads(out[-2]), json.loads(out[-1])["program_spans"]
     assert res["correct"] is True and "host_ms_per_step" in res["metrics"]
+    # no TPU here: the readers of device time find no operations
+    assert "prefill_idle_ms_per_wave" not in res["metrics"]
+    assert "decode_idle_ms_per_step" not in res["metrics"]
+    assert res["metrics"]["sched_ms_per_step"]["value"] == \
+        got["sched_ms_per_step"]
+    assert res["metrics"]["prefill_cache_loads_per_wave"]["value"] == \
+        got["prefill_cache_loads_per_wave"]
     assert got["steps"] > 0
     assert got["sched_ms_per_step"] > 0
     assert got["prefill_cache_loads_per_wave"] is not None

@@ -3,12 +3,16 @@ breakdown, on the trace's own clock.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote into a plain
 dict: the device operations of every TPU (start and end in ns, name,
-chip) and the benchmark's own host spans (the ``bench.*`` annotations:
-``window``, ``step``, and the backend calls ``prefill_wave``, ``decode``,
-``splice``, ``extract``).  Both sit on the trace's clock, so a span's
-device time is the part of the operations' union that falls inside it.
-Everything after ``load`` works on that dict, which is what the tests
-check on a trace recorded on the chip.
+chip), the benchmark's own host spans (``marks``: the ``bench.*``
+annotations ``window``, ``step``, and the backend calls ``prefill_wave``,
+``decode``, ``splice``, ``extract``), the program's own spans
+(``program_marks``: the ``repro.*`` annotations of the span log the
+harness attaches in traced runs) and each program the TPUs ran
+(``modules``: the jitted call's name, e.g. ``jit_paged_decode``).  All sit
+on the trace's clock, so a span's device time is the part of the
+operations' union that falls inside it.  Everything after ``load`` works
+on that dict, which is what the tests check on a trace recorded on the
+chip.
 """
 
 from __future__ import annotations
@@ -18,34 +22,49 @@ from pathlib import Path
 
 DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
 
 def load(trace_dir) -> dict:
+    """``ops`` (start, end, name, chip), ``marks`` and ``program_marks``
+    (name without its prefix, start, end) and ``modules`` (name without
+    its ``(id)``, start, end, chip), in ns, from the newest profile under
+    ``trace_dir``."""
     from jax.profiler import ProfileData
     paths = sorted(Path(trace_dir).rglob("*.xplane.pb"))
     if not paths:
-        return {"ops": [], "marks": [], "devices": 0}
+        return {"ops": [], "marks": [], "devices": 0, "program_marks": [],
+                "modules": []}
     pd = ProfileData.from_file(str(paths[-1]))
     ops, marks, devices = [], [], set()
+    program_marks, modules = [], []
     for plane in pd.planes:
         m = DEVICE.match(plane.name)
         if m:
             devices.add(int(m.group(1)))
             for line in plane.lines:
-                if line.name != OPS_LINE:
-                    continue
-                for e in line.events:
-                    ops.append((e.start_ns, e.start_ns + e.duration_ns,
-                                e.name, int(m.group(1))))
+                if line.name == OPS_LINE:
+                    ops.extend((e.start_ns, e.start_ns + e.duration_ns,
+                                e.name, int(m.group(1))) for e in line.events)
+                elif line.name == MODULES_LINE:
+                    modules.extend((e.name.split("(")[0], e.start_ns,
+                                    e.start_ns + e.duration_ns,
+                                    int(m.group(1))) for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith("bench."):
                         marks.append((e.name[6:], e.start_ns,
                                       e.start_ns + e.duration_ns))
+                    elif e.name.startswith("repro."):
+                        program_marks.append((e.name[6:], e.start_ns,
+                                              e.start_ns + e.duration_ns))
     ops.sort()
     marks.sort(key=lambda x: x[1])
-    return {"ops": ops, "marks": marks, "devices": len(devices)}
+    program_marks.sort(key=lambda x: (x[1], -x[2]))
+    modules.sort(key=lambda x: x[1])
+    return {"ops": ops, "marks": marks, "devices": len(devices),
+            "program_marks": program_marks, "modules": modules}
 
 
 def window(td: dict):
